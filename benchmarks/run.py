@@ -24,6 +24,7 @@ from benchmarks import (
     bench_strategies,
 )
 from benchmarks.common import CSV
+from repro.launch.compile_cache import setup_compile_cache
 
 MODULES = {
     "applicability": bench_applicability,
@@ -42,6 +43,7 @@ def main(argv=None) -> None:
     ap.add_argument("--only", default=None, choices=list(MODULES))
     args = ap.parse_args(argv)
 
+    setup_compile_cache()
     csv = CSV()
     csv.header()
     mods = {args.only: MODULES[args.only]} if args.only else MODULES

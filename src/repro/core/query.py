@@ -168,7 +168,7 @@ def _jvp_rule(primals, tangents, *, name, in_tree):
     def _zero_tan(p, t):
         if not isinstance(t, ad.Zero):
             return t
-        aval = jax.core.get_aval(p)
+        aval = jax.typeof(p)
         if jnp.issubdtype(aval.dtype, jnp.inexact):
             return jnp.zeros(aval.shape, aval.dtype)
         return np.zeros(aval.shape, _dtypes.float0)  # int/bool primals

@@ -25,9 +25,6 @@ __all__ = ["batched_gather"]
 
 PIPE = 8  # DMA descriptors kept in flight
 
-# Renamed across JAX versions (MemorySpace <-> TPUMemorySpace).
-_MEMSPACE = getattr(pltpu, "TPUMemorySpace", None) or pltpu.MemorySpace
-
 
 def _kernel(ids_ref, table_ref, o_ref, sems, *, bn):
     blk = pl.program_id(0)
@@ -81,7 +78,7 @@ def batched_gather(table, ids, *, bn: int = 256, interpret: bool = False):
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=1,
             grid=(n // bn,),
-            in_specs=[pl.BlockSpec(memory_space=_MEMSPACE.ANY)],
+            in_specs=[pl.BlockSpec(memory_space=pltpu.MemorySpace.ANY)],
             out_specs=pl.BlockSpec((bn, d), lambda blk, ids: (blk, 0)),
             scratch_shapes=[pltpu.SemaphoreType.DMA((PIPE,))],
         ),

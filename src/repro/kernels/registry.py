@@ -18,10 +18,12 @@ Dispatch semantics (identical to the historical per-op wrappers):
 
 * ``use_kernel=False`` → the jnp reference, always (models may call ops
   unconditionally).
-* On TPU the Pallas kernel runs compiled; off-TPU it runs only when
-  ``interpret=True`` is reachable (the kernel body executes on CPU exactly
-  as it would on the TPU grid — the test path), and ``interpret`` is
-  forced on so a CPU caller can never launch an uncompiled TPU kernel.
+* On TPU the Pallas kernel always runs compiled: ``interpret`` (and
+  ``REPRO_KERNEL_INTERPRET``) is ignored there, so nothing can quietly
+  swap the device kernel for the interpreter or the reference.
+* Off TPU the kernel runs only when ``interpret=True`` (the kernel body
+  executes on CPU exactly as it would on the TPU grid — the test path);
+  otherwise the reference runs.
 * An op whose ``supports`` predicate rejects the concrete operands falls
   back to the reference — a shape outside the kernel's envelope is a
   fallback, not an error.
@@ -51,7 +53,8 @@ def interpret_default() -> bool:
     (``1``/``true``/``yes``): CI's CPU-only ``kernels`` job sets it so the
     serving engine's decode ticks execute the Pallas kernel bodies under
     interpret mode on every PR, instead of only on TPU.  Off by default —
-    off-TPU callers then take the pure-jnp reference path.
+    off-TPU callers then take the pure-jnp reference path.  It has no
+    effect on TPU, where :func:`dispatch` always compiles the kernel.
     """
     return os.environ.get("REPRO_KERNEL_INTERPRET", "").strip().lower() in (
         "1", "true", "yes")
@@ -143,5 +146,5 @@ def dispatch(name: str, args: tuple, *, common: Optional[dict] = None,
     on_tpu = jax.default_backend() == "tpu"
     eligible = (op.supports is None or op.supports(*args, **ck, **kk))
     if use_kernel and (on_tpu or interpret) and eligible:
-        return op.kernel(*args, **ck, **kk, interpret=interpret or not on_tpu)
+        return op.kernel(*args, **ck, **kk, interpret=not on_tpu)
     return op.ref(*args, **ck)

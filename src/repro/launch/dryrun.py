@@ -316,9 +316,9 @@ def run_cell(arch_name: str, shape_name: str, mesh_kind: str,
 
     # Loop-aware per-device cost: XLA's cost_analysis reports while bodies
     # once; analyze_hlo multiplies by trip counts (see hlo_cost.py).
-    from repro.launch.hlo_cost import analyze_hlo, cost_analysis_dict
+    from repro.launch.hlo_cost import analyze_hlo
 
-    cost = cost_analysis_dict(compiled)
+    cost = compiled.cost_analysis()
 
     lcost = analyze_hlo(hlo)
     flops = lcost.flops

@@ -30,21 +30,8 @@ import dataclasses
 import re
 from typing import Dict, List, Optional
 
-__all__ = ["analyze_hlo", "HloCost", "cost_analysis_dict"]
+__all__ = ["analyze_hlo", "HloCost"]
 
-
-def cost_analysis_dict(compiled) -> dict:
-    """Normalize ``compiled.cost_analysis()`` across JAX versions.
-
-    Older JAX returns a one-element list of per-device dicts; newer JAX
-    returns the dict directly.  Callers doing ``cost.get("flops")`` on the
-    list form crash with ``AttributeError: 'list' object has no attribute
-    'get'`` — route every access through this helper instead.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
 
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "bf16": 2, "f16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

@@ -152,8 +152,6 @@ def moe(p: dict, cfg: ModelConfig, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
     )
 
     if use_shard_map:
-        from jax.experimental.shard_map import shard_map
-
         n_model = mesh.shape["model"]
         E_loc = E // n_model
         dp_spec = dp_axes if dp_axes else None
@@ -165,7 +163,7 @@ def moe(p: dict, cfg: ModelConfig, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
                 x_l, ei_l, gv_l, pos_l, C, e_off, E_loc)
             return jax.lax.psum(y_part, "model")
 
-        y = shard_map(
+        y = jax.shard_map(
             block, mesh=mesh,
             in_specs=(
                 P(dp_spec, None, None),        # x
@@ -177,7 +175,7 @@ def moe(p: dict, cfg: ModelConfig, x) -> Tuple[jnp.ndarray, jnp.ndarray]:
                 P("model", None, None),        # w_out
             ),
             out_specs=P(dp_spec, None, None),
-            check_rep=False,
+            check_vma=False,
         )(x, expert_idx, gate_vals, pos,
           p["experts"]["w_gate"], p["experts"]["w_in"], p["experts"]["w_out"])
     else:

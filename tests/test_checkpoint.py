@@ -117,7 +117,8 @@ root = sys.argv[1]
 mgr = CheckpointManager(root)
 like = {"w": jax.ShapeDtypeStruct((16, 8), jnp.float32)}
 step, params, _ = mgr.restore_latest(like)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 sharded = jax.device_put(params["w"], NamedSharding(mesh, P("data", "model")))
 assert len(sharded.addressable_shards) == 8
 total = float(jnp.sum(sharded))

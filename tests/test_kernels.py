@@ -230,12 +230,36 @@ def test_registry_dispatch_policy():
                           kernel=lambda *a, **k: None)
 
 
+@pytest.mark.parametrize("backend,use_kernel,interpret,want", [
+    ("tpu", True, False, ("kernel", False)),
+    ("tpu", True, True, ("kernel", False)),   # never interpreted on TPU
+    ("tpu", False, True, ("ref", None)),
+    ("cpu", True, True, ("kernel", True)),
+    ("cpu", True, False, ("ref", None)),
+])
+def test_registry_dispatch_rule_by_backend(monkeypatch, backend, use_kernel,
+                                           interpret, want):
+    """On TPU dispatch always runs the compiled kernel unless
+    use_kernel=False; off TPU the kernel runs only in interpret mode."""
+    from repro.kernels import registry
+
+    op = registry.KernelOp(
+        "probe", ref=lambda x: ("ref", None),
+        kernel=lambda x, interpret: ("kernel", interpret))
+    monkeypatch.setitem(registry._OPS, "probe", op)
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: backend)
+    assert registry.dispatch("probe", (0,), use_kernel=use_kernel,
+                             interpret=interpret) == want
+
+
 # --------------------------------------------------------- paged attention
 
 @pytest.mark.parametrize("b,hq,hkv,np_,ps,d", [
     (2, 4, 2, 8, 16, 64),
     (1, 8, 2, 4, 32, 64),
     (3, 4, 4, 6, 8, 32),   # MHA, non-pow2 page count
+    (2, 16, 16, 3, 16, 128),  # OLMo-1B heads and head_dim
+    (2, 32, 8, 3, 16, 128),   # GQA at head_dim 128
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_paged_decode_attention_sweep(b, hq, hkv, np_, ps, d, dtype):

@@ -51,7 +51,8 @@ state = init_state(params)
 _, _, m_ref = step(params, state, batch)
 
 # sharded execution on a 4x2 mesh
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 p_sh = param_shardings(mesh, jax.eval_shape(lambda: arch.init(key)))
 params_s = jax.device_put(params, p_sh)
 state_s = init_state(params_s)
@@ -89,11 +90,11 @@ from repro.models.registry import get_arch
 from repro.models.config import ShapeSpec
 from repro.distributed.sharding import param_shardings, mesh_context
 from repro.launch.dryrun import parse_collective_bytes, _input_shardings
-from repro.launch.hlo_cost import cost_analysis_dict
 
 arch = get_arch("deepseek-moe-16b")
 arch = dataclasses.replace(arch, cfg=arch.cfg.reduced())
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 shape = ShapeSpec("mini_train", 32, 8, "train")
 specs = arch.input_specs(shape)
 params_sds = jax.eval_shape(lambda: arch.init(jax.random.PRNGKey(0)))
@@ -108,7 +109,7 @@ with mesh_context(mesh):
     lowered = jax.jit(fwd, in_shardings=(p_sh, in_sh)).lower(params_sds, specs)
     compiled = lowered.compile()
 coll = parse_collective_bytes(compiled.as_text())
-cost = cost_analysis_dict(compiled)  # list vs dict varies by JAX version
+cost = compiled.cost_analysis()
 print(json.dumps({
     "collective_count": coll["total_count"],
     "collective_bytes": coll["total_bytes"],

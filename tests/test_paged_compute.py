@@ -228,3 +228,23 @@ def test_interpret_default_env(setup, monkeypatch):
     eng = PagedInferenceEngine(arch, params, n_lanes=1, max_prompt_len=16,
                                max_len=16, page_size=8)
     assert eng._interpret is True
+
+
+def test_serve_requests_drives_paged_main_path(setup):
+    """The launcher's entry point serves through the scheduler into the
+    paged engine (paged compute, one decode dispatch per tick) and returns
+    every request with its full token budget."""
+    from repro.launch.serve import serve_requests
+
+    arch, params = setup
+    rng = np.random.default_rng(3)
+    reqs = [Request(rid=i, prompt=rng.integers(1, 200, size=n).astype(np.int32),
+                    max_new_tokens=4) for i, n in enumerate((5, 12, 9))]
+    eng, sched, done = serve_requests(arch, params, reqs, lanes=2,
+                                      max_len=32, max_prompt_len=16,
+                                      page_size=8)
+    assert isinstance(eng, PagedInferenceEngine) and eng.paged_compute
+    assert sched.resilience is None
+    assert sorted(r.rid for r in done) == [0, 1, 2]
+    assert all(len(r.generated) == 4 for r in done)
+    assert eng.decode_steps > 0 and eng.dispatches > 0

@@ -55,36 +55,6 @@ def fail(reason: str) -> None:
     sys.exit(1)
 
 
-class CompileLog:
-    """Backend compile seconds and persistent-cache hits/misses, from
-    JAX's monitoring events."""
-
-    def __init__(self):
-        import jax
-
-        self.seconds = 0.0
-        self.compiles = 0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, secs, **_kw):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.seconds += secs
-            self.compiles += 1
-
-    def _event(self, event, **_kw):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
-
-    def line(self) -> str:
-        return (f"{self.compiles} backend compiles, {self.seconds:.1f}s; "
-                f"persistent cache {self.hits} hits, {self.misses} misses")
-
-
 def make_requests(prompts, max_new):
     from repro.serving.request import Request
 
@@ -215,11 +185,15 @@ def main() -> None:
 
     from repro.launch.compile_cache import setup_compile_cache
     from repro.models.registry import get_arch
+    from repro.serving import tracing
 
     cache = setup_compile_cache()
     print(f"compile cache: {jax.config.jax_compilation_cache_dir} "
           f"({'set here' if cache else 'from JAX_COMPILATION_CACHE_DIR'})")
-    log = CompileLog()
+    # program spans on, so that each compile is charged to the step that
+    # asked for it
+    tracing.enable(True)
+    log = tracing.CompileLog()
     dev = jax.devices()[0]
     t_start = time.perf_counter()
 

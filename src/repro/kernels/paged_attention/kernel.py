@@ -127,6 +127,9 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, lengths,
         ),
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         interpret=interpret,
+        # the kernel's name in compiled programs and device traces, apart
+        # from any other Pallas call in the same step
+        name="paged_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
       qg, k_pages, v_pages)
     return out.reshape(b, hq, d)

@@ -62,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.registry import Arch
+from repro.serving.tracing import span
 
 __all__ = ["HostSpillPool", "InferenceEngine", "KVPartition", "StagedPrefill",
            "proportional_shares"]
@@ -405,6 +406,14 @@ class InferenceEngine:
         # Lock-guarded: the speculative prefill thread dispatches too.
         self.dispatches = 0
         self._dispatch_lock = threading.Lock()
+        # Blocking reads of device arrays into host memory, each of which
+        # waits for the device (:meth:`_host`): a paged decode tick reads
+        # one length per active lane, then its new tokens.
+        self.host_reads = 0
+        # Real prompt tokens and padded bucket slots (batch x prompt) of
+        # every prefill dispatch; 1 - real / bucket is the padding share.
+        self.prefill_real_tokens = 0
+        self.prefill_bucket_tokens = 0
         # template -> pinned (batch, prompt) prefill bucket: each template
         # converges on ONE compiled prefill shape (monotone max of what it
         # has needed), so a template burst stops recompiling per batch size.
@@ -455,6 +464,20 @@ class InferenceEngine:
         """Record ``n`` jitted model-step dispatches (thread-safe)."""
         with self._dispatch_lock:
             self.dispatches += n
+
+    def _count_prefill(self, staged: StagedPrefill, n: int) -> StagedPrefill:
+        """Record the real and bucket tokens of a prefill dispatch of
+        ``n`` requests (thread-safe: the speculative thread dispatches)."""
+        with self._dispatch_lock:
+            self.prefill_real_tokens += int(np.sum(staged.plens[:n]))
+            self.prefill_bucket_tokens += staged.shape[0] * staged.shape[1]
+        return staged
+
+    def _host(self, x, copy: bool = False) -> np.ndarray:
+        """``x`` in host memory, a writable copy with ``copy``: one
+        blocking read of a device array, counted in :attr:`host_reads`."""
+        self.host_reads += 1
+        return np.array(x) if copy else np.asarray(x)
 
     # ------------------------------------------------------------- admission
     def admit(self, requests: Sequence, template: Optional[str] = None
@@ -510,8 +533,8 @@ class InferenceEngine:
                         for r in requests]
             if len(requests) == 1:
                 if len(cprompts[0]) > chunk:
-                    return self._chunked_dispatch(
-                        requests[0], cprompts[0], template, chunk)
+                    return self._count_prefill(self._chunked_dispatch(
+                        requests[0], cprompts[0], template, chunk), 1)
                 # A prompt that fits one chunk: ordinary one-shot below.
             elif any(len(p) > chunk for p in cprompts):
                 # A BATCH of oversized prompts: one single-request chunked
@@ -521,11 +544,11 @@ class InferenceEngine:
                 # interleaves decode ticks between every part's chunks.
                 parts = [self._chunked_dispatch(r, p, template, chunk)
                          for r, p in zip(requests, cprompts)]
-                return StagedPrefill(
+                return self._count_prefill(StagedPrefill(
                     template, list(requests), None, None,
                     np.concatenate([pt.plens for pt in parts]),
                     (len(requests), int(max(len(p) for p in cprompts))),
-                    parts=parts)
+                    parts=parts), len(requests))
         bsz = _bucket(len(requests))
         # Bucket the prompt axis to the batch's longest (truncated) prompt:
         # lane-homogeneous admission (scheduler groups by template) means
@@ -538,17 +561,20 @@ class InferenceEngine:
             bsz = max(bsz, pinned[0])
             plen = max(plen, pinned[1])
             self.template_shapes[template] = (bsz, plen)
-        toks = np.zeros((bsz, plen), np.int32)
-        plens = np.ones((bsz,), np.int32)
-        for i, p in enumerate(prompts):
-            toks[i, : len(p)] = p  # right-pad; causal mask hides pad keys
-            plens[i] = len(p)
-        first, cache = self._prefill(
-            self.params, jnp.asarray(toks), jnp.asarray(plens), self.max_len
-        )
+        with span("engine.prefill", rids=[r.rid for r in requests],
+                  shape=(bsz, plen)):
+            toks = np.zeros((bsz, plen), np.int32)
+            plens = np.ones((bsz,), np.int32)
+            for i, p in enumerate(prompts):
+                toks[i, : len(p)] = p  # right-pad; causal mask hides pad keys
+                plens[i] = len(p)
+            first, cache = self._prefill(
+                self.params, jnp.asarray(toks), jnp.asarray(plens),
+                self.max_len)
         self._count_dispatch()
-        return StagedPrefill(template, list(requests), first, cache,
-                             plens, (bsz, plen))
+        return self._count_prefill(
+            StagedPrefill(template, list(requests), first, cache, plens,
+                          (bsz, plen)), len(requests))
 
     def _chunked_dispatch(self, r, prompt: np.ndarray,
                           template: Optional[str], chunk: int) -> StagedPrefill:
@@ -565,9 +591,10 @@ class InferenceEngine:
         the template's pinned batch bucket."""
         S = len(prompt)
         c0 = min(chunk, S)
-        first, cache = self._prefill(
-            self.params, jnp.asarray(prompt[None, :c0]),
-            jnp.asarray([c0], jnp.int32), self.max_len)
+        with span("engine.prefill", rids=[r.rid], shape=(1, c0)):
+            first, cache = self._prefill(
+                self.params, jnp.asarray(prompt[None, :c0]),
+                jnp.asarray([c0], jnp.int32), self.max_len)
         self._count_dispatch()
         pending = [prompt[None, i: i + chunk] for i in range(c0, S, chunk)]
         return StagedPrefill(
@@ -598,8 +625,11 @@ class InferenceEngine:
                     break
             return staged.complete
         toks = staged.pending.pop(0)
-        logits, staged.cache, staged.lengths_dev = self._extend(
-            self.params, staged.cache, jnp.asarray(toks), staged.lengths_dev)
+        with span("engine.prefill_resume",
+                  rids=[r.rid for r in staged.requests]):
+            logits, staged.cache, staged.lengths_dev = self._extend(
+                self.params, staged.cache, jnp.asarray(toks),
+                staged.lengths_dev)
         self._count_dispatch()
         if not staged.pending:
             staged.first = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -634,19 +664,20 @@ class InferenceEngine:
             "commit_prefill() beyond this template's free lanes"
         if not reqs:
             return staged.shape
-        first = np.asarray(staged.first)  # materializes the async dispatch
-        lanes = [self.partition.alloc(staged.template) for _ in reqs]
-        self._insert_staged(staged, lanes)
-        lt = np.array(self.last_token)
-        ln = np.array(self.lengths)
-        for i, (r, lane) in enumerate(zip(reqs, lanes)):
-            r.lane = lane
-            r.generated.append(int(first[i]))
-            lt[lane] = first[i]
-            ln[lane] = staged.plens[i]  # real prompt length; decode writes here
-            self.active[lane] = True
-        self.last_token = jnp.asarray(lt)
-        self.lengths = jnp.asarray(ln)
+        with span("engine.commit", rids=[r.rid for r in reqs]):
+            first = self._host(staged.first)  # waits for the async dispatch
+            lanes = [self.partition.alloc(staged.template) for _ in reqs]
+            self._insert_staged(staged, lanes)
+            lt = self._host(self.last_token, copy=True)
+            ln = self._host(self.lengths, copy=True)
+            for i, (r, lane) in enumerate(zip(reqs, lanes)):
+                r.lane = lane
+                r.generated.append(int(first[i]))
+                lt[lane] = first[i]
+                ln[lane] = staged.plens[i]  # real prompt length; decode writes here
+                self.active[lane] = True
+            self.last_token = jnp.asarray(lt)
+            self.lengths = jnp.asarray(ln)
         self.prefill_calls += 1
         return staged.shape
 
@@ -662,12 +693,45 @@ class InferenceEngine:
 
     # ----------------------------------------------------------------- tick
     def decode_tick(self) -> dict[int, int]:
-        """One batched decode step over all lanes → ``{lane: token}``."""
-        if not self.active.any():
-            return {}
+        """One batched decode step over all lanes → ``{lane: token}``.
+
+        Three steps, each a span: ``engine.decode.prepare`` (host work
+        before the step: :meth:`_prepare_decode`), ``engine.decode.dispatch``
+        (the step program and the eager length update, no read:
+        :meth:`_dispatch_decode`) and ``engine.decode.readback`` (the one
+        read of the new tokens).  The tick's span carries the lanes it
+        found active and :attr:`host_reads` at its start."""
+        with span("engine.decode_tick", lanes=np.nonzero(self.active)[0],
+                  host_reads=self.host_reads):
+            with span("engine.decode.prepare"):
+                args = self._prepare_decode()
+            if args is None:
+                return {}
+            with span("engine.decode.dispatch"):
+                nxt = self._dispatch_decode(*args)
+            with span("engine.decode.readback"):
+                out = self._host(nxt)
+                return {lane: int(out[lane])
+                        for lane in np.nonzero(self.active)[0]}
+
+    def _prepare_decode(self) -> Optional[tuple]:
+        """The arguments of :meth:`_dispatch_decode`, or ``None`` when no
+        lane is active."""
+        return () if self.active.any() else None
+
+    def _dispatch_decode(self):
+        """Dispatch one dense decode step; returns the new tokens, still
+        on the device."""
         nxt, self.cache = self._decode(
             self.params, self.last_token, self.cache, self.lengths
         )
+        self._advance_lanes(nxt)
+        return nxt
+
+    def _advance_lanes(self, nxt) -> None:
+        """After a decode dispatch: every active lane's length grows by
+        one (an eager device op; nothing is read) and ``nxt`` becomes its
+        last token."""
         self._count_dispatch()
         self.lengths = jnp.where(
             jnp.asarray(self.active), jnp.minimum(self.lengths + 1, self.max_len - 1),
@@ -675,8 +739,6 @@ class InferenceEngine:
         )
         self.last_token = nxt
         self.decode_steps += 1
-        out = np.asarray(nxt)
-        return {lane: int(out[lane]) for lane in np.nonzero(self.active)[0]}
 
     def retire(self, lane: int) -> None:
         """Free a lane (request finished or force-retired); the lane
@@ -703,9 +765,9 @@ class InferenceEngine:
             return False
         entry = {
             "rows": jax.tree_util.tree_map(
-                lambda a: np.asarray(a[:, lane]), self.cache),
-            "length": int(np.asarray(self.lengths)[lane]),
-            "last": int(np.asarray(self.last_token)[lane]),
+                lambda a: self._host(a[:, lane]), self.cache),
+            "length": int(self._host(self.lengths)[lane]),
+            "last": int(self._host(self.last_token)[lane]),
         }
         self.kv_bytes_moved += sum(
             a.nbytes for a in jax.tree_util.tree_leaves(entry["rows"]))
@@ -744,8 +806,8 @@ class InferenceEngine:
             lambda dst, src: dst.at[:, lane].set(
                 jnp.asarray(src).astype(dst.dtype)),
             self.cache, rows)
-        ln = np.array(self.lengths)
-        lt = np.array(self.last_token)
+        ln = self._host(self.lengths, copy=True)
+        lt = self._host(self.last_token, copy=True)
         ln[lane] = entry["length"]
         lt[lane] = entry["last"]
         self.lengths = jnp.asarray(ln)

@@ -786,11 +786,11 @@ class PagedInferenceEngine(InferenceEngine):
                                         * (npg - k) * ps
                                         * int(np.prod(a.shape[3:])))
         self.prefix_index.insert(lane, prompt)
-        first_tok = int(np.asarray(first)[0])
+        first_tok = int(self._host(first)[0])
         r.lane = lane
         r.generated.append(first_tok)
-        ln = np.array(self.lengths)
-        lt = np.array(self.last_token)
+        ln = self._host(self.lengths, copy=True)
+        lt = self._host(self.last_token, copy=True)
         ln[lane] = plen
         lt[lane] = first_tok
         self.lengths = jnp.asarray(ln)
@@ -905,43 +905,50 @@ class PagedInferenceEngine(InferenceEngine):
         return True
 
     # ----------------------------------------------------------------- tick
-    def decode_tick(self) -> dict[int, int]:
-        """One paged decode step: flush restore tails, grow block tables
-        (evicting under page pressure), then dispatch the paged kernel —
-        fused with any staged prefill chunk.  Dense-compute mode runs the
+    def _prepare_decode(self) -> Optional[tuple]:
+        """Host work of a paged decode tick before its dispatch: flush
+        restore tails, grow block tables (evicting under page pressure),
+        fence copy-on-write pages, and upload the tables, the lane mask and
+        the sampling parameters.  The step is fused with any staged
+        prefill chunk.  Dense-compute mode grows the tables of the
         ordinary dense decode step instead."""
+        self._flush_restores()
         if not self.paged_compute:
-            self._flush_restores()
             if self.active.any():
-                ln = np.asarray(self.lengths)
+                ln = self._host(self.lengths)
                 for lane in np.nonzero(self.active)[0]:
                     # decode writes position `length` this tick: its page
                     # must be in the table before the write.
                     self._ensure_pages(int(lane),
                                        int(ln[lane]) // self.page_size + 1)
-            return super().decode_tick()
-        self._flush_restores()
+            return super()._prepare_decode()
         part, self._fused_chunk = self._fused_chunk, None
         if not self.active.any():
             if part is not None:  # nothing to fuse with: plain resume
                 self.prefill_resume(part)
-            return {}
+            return None
         for lane in np.nonzero(self.active)[0]:
             lane = int(lane)
             if not self.active[lane]:
                 continue  # evicted by an earlier lane's growth this tick
-            length = int(np.asarray(self.lengths)[lane])
+            length = int(self._host(self.lengths)[lane])
             self._ensure_pages(lane, length // self.page_size + 1)
             if self.active[lane]:
                 self._cow_guard(lane, length)
         if not self.active.any():  # growth pressure evicted every lane
             if part is not None:
                 self.prefill_resume(part)
-            return {}
-        tables = self._device_tables()
-        active_dev = jnp.asarray(self.active)
-        temps = jnp.asarray(self.lane_temps)
-        seeds = jnp.asarray(self.lane_seeds)
+            return None
+        return (part, self._device_tables(), jnp.asarray(self.active),
+                jnp.asarray(self.lane_temps), jnp.asarray(self.lane_seeds))
+
+    def _dispatch_decode(self, *args):
+        """Dispatch the paged decode step prepared by
+        :meth:`_prepare_decode` (fused with ``part``'s next chunk when one
+        is staged); returns the new tokens, still on the device."""
+        if not self.paged_compute:
+            return super()._dispatch_decode()
+        part, tables, active_dev, temps, seeds = args
         if part is None:
             nxt, self.cache = self._paged_decode(
                 self.params, self.last_token, self.cache, self.lengths,
@@ -955,14 +962,8 @@ class PagedInferenceEngine(InferenceEngine):
             if not part.pending:
                 part.first = cfirst
             self.fused_folds += 1
-        self._count_dispatch()
-        self.lengths = jnp.where(
-            jnp.asarray(self.active),
-            jnp.minimum(self.lengths + 1, self.max_len - 1), self.lengths)
-        self.last_token = nxt
-        self.decode_steps += 1
-        out = np.asarray(nxt)
-        return {lane: int(out[lane]) for lane in np.nonzero(self.active)[0]}
+        self._advance_lanes(nxt)
+        return nxt
 
     def _device_tables(self):
         """All lanes' block tables as one (n_lanes, pages_per_lane) int32
@@ -1028,7 +1029,7 @@ class PagedInferenceEngine(InferenceEngine):
             self.retire(lane)
             return False
         self._flush_restores(lane)  # device rows must be whole before copy
-        length = int(np.asarray(self.lengths)[lane])
+        length = int(self._host(self.lengths)[lane])
         ps = self.page_size
         npg = max(1, self.pool.pages_for(length))
         n_rows = min(self.max_len, npg * ps)
@@ -1041,20 +1042,20 @@ class PagedInferenceEngine(InferenceEngine):
             prefix_pages = list(pages[:keep])
             idx = jnp.asarray(np.asarray(pages[keep:npg], np.int32))
             rows = jax.tree_util.tree_map(
-                lambda a: np.asarray(
+                lambda a: self._host(
                     a[:, idx].reshape(a.shape[0], (npg - keep) * ps,
                                       *a.shape[3:])
                     [:, : n_rows - tail_start]),
                 self.cache)
         else:
             rows = jax.tree_util.tree_map(
-                lambda a: np.asarray(a[:, lane, :n_rows])
-                if self._seq_leaf(a) else np.asarray(a[:, lane]), self.cache)
+                lambda a: self._host(a[:, lane, :n_rows])
+                if self._seq_leaf(a) else self._host(a[:, lane]), self.cache)
         entry = {
             "rows": rows,
             "n_rows": n_rows,
             "length": length,
-            "last": int(np.asarray(self.last_token)[lane]),
+            "last": int(self._host(self.last_token)[lane]),
             "tail_start": tail_start,
             "prefix_pages": prefix_pages,
             "temp": float(self.lane_temps[lane]),
@@ -1133,8 +1134,8 @@ class PagedInferenceEngine(InferenceEngine):
             self._open_table(lane, entry["length"])
         if head < n_rows:
             self._pending_restore[lane] = (rows, head, n_rows, tail_start)
-        ln = np.array(self.lengths)
-        lt = np.array(self.last_token)
+        ln = self._host(self.lengths, copy=True)
+        lt = self._host(self.last_token, copy=True)
         ln[lane] = entry["length"]
         lt[lane] = entry["last"]
         self.lengths = jnp.asarray(ln)
@@ -1218,7 +1219,7 @@ class PagedInferenceEngine(InferenceEngine):
             k_pages = jnp.reshape(k0, (self.n_lanes * ppl, ps, hkv, hd))
             v_pages = jnp.reshape(v0, (self.n_lanes * ppl, ps, hkv, hd))
         tables = np.stack([self.pool.block_table(lane, ppl) for lane in lanes])
-        lengths = np.asarray(self.lengths)[lanes].astype(np.int32)
+        lengths = self._host(self.lengths)[lanes].astype(np.int32)
         return {"k_pages": k_pages, "v_pages": v_pages,
                 "block_tables": jnp.asarray(tables),
                 "lengths": jnp.asarray(lengths), "lanes": lanes}

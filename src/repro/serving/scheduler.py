@@ -125,6 +125,7 @@ from repro.core.resilience import (
 from repro.core.strategies import BatchingStrategy, PureAsync
 from repro.serving.engine import InferenceEngine
 from repro.serving.request import Request
+from repro.serving.tracing import span
 
 __all__ = ["ContinuousBatchingScheduler"]
 
@@ -950,7 +951,24 @@ class ContinuousBatchingScheduler:
     def tick(self) -> list[Request]:
         """One scheduling round: commit the staged speculative prefill,
         admit per strategy (per lane), dispatch the next speculation, run
-        one decode step."""
+        one decode step.  The round is the span ``sched.tick``; admission
+        (:meth:`_admit_round`) is ``sched.admit`` and the handling of the
+        new tokens (:meth:`_take_tokens`) ``sched.tokens``, with the
+        engine's ``engine.decode_tick`` between them."""
+        with span("sched.tick"):
+            with span("sched.admit"):
+                self._admit_round()
+            # 2) one batched decode step over all active lanes
+            t0 = time.perf_counter()
+            tokens = self._decode_with_recovery()
+            decode_dt = time.perf_counter() - t0
+            self.stats.decode_ticks += 1
+            with span("sched.tokens"):
+                return self._take_tokens(tokens, decode_dt)
+
+    def _admit_round(self) -> None:
+        """Steps 0 to 1.5 of a tick: failure-domain upkeep, the speculative
+        commit, admission from the ready lanes and the next speculation."""
         # 0) failure domain first: quarantined lanes whose cooldown has
         # elapsed rejoin their pools before admission counts free lanes,
         # and a template whose submissions keep failing surfaces as a
@@ -1082,18 +1100,18 @@ class ContinuousBatchingScheduler:
         if self.overlap and len(self._staged) < self.spec_depth:
             self._dispatch_speculative(select)
 
-        # 2) one batched decode step over all active lanes
-        finished: list[Request] = []
-        t0 = time.perf_counter()
-        tokens = self._decode_with_recovery()
-        decode_dt = time.perf_counter() - t0
-        self.stats.decode_ticks += 1
+    def _take_tokens(self, tokens: dict, decode_dt: float) -> list[Request]:
+        """The end of a tick, after its decode step: decode-time feedback
+        to the lane policy, then each new token appended to its request;
+        finished requests retire (and are returned), stragglers are
+        spilled and re-queued."""
         if self.policy is not None and tokens:
             # Per-lane decode feedback: every template with a request in this
             # tick's batch gets the tick duration — the per-token side of its
             # cost model, next to the prefill F + n·c fit.
             for tmpl in {r.template for r in self.running.values()}:
                 self.policy.observe_decode(tmpl, decode_dt)
+        finished: list[Request] = []
         for lane, tok in tokens.items():
             r = self.running.get(lane)
             if r is None:

@@ -9,6 +9,8 @@ the worker running this file loads the TPU library.
 """
 from __future__ import annotations
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -54,7 +56,8 @@ def no_compile_cache():
 ])
 def test_paged_kernel_compiles_for_v5e(one_chip, no_compile_cache, hq, hkv):
     """The paged decode kernel at OLMo-1B widths (B=8, D=128, page 16,
-    64 pages per request, bf16) lowers to a Mosaic custom call."""
+    64 pages per request, bf16) lowers to a Mosaic custom call named
+    ``paged_attention``."""
     b, d, ps, np_ = 8, 128, 16, 64
     n_phys = b * np_ + 1
 
@@ -68,7 +71,10 @@ def test_paged_kernel_compiles_for_v5e(one_chip, no_compile_cache, hq, hkv):
         sds((b, np_), jnp.int32),
         sds((b,), jnp.int32),
     ).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the kernel keeps its own name in the compiled program
+    assert re.search(r"%paged_attention[.\d]* = .*tpu_custom_call", text)
 
 
 # ------------------------------------------------------------ compile cache

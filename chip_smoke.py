@@ -12,6 +12,8 @@ are random, drawn from ``SEED``.  Phases, each of which raises on failure:
    backend is a TPU (there is no CPU fallback);
 2. compile cache — :func:`repro.launch.compile_cache.setup_compile_cache`;
 3. serve — 16 requests, prompts of 32-256 tokens, 32 new tokens each;
+   every decode tick must update the page pool in place (the runtime
+   took the donated pool: ``pool_in_place_ticks`` equals the ticks);
 4. kernel — the engine's compiled paged decode program must contain a
    ``tpu_custom_call`` (the kernel, not the jnp reference);
 5. parity — on live engine state: the attention op, kernel vs reference,
@@ -81,8 +83,12 @@ def serve_phase(arch, params, prompts, *, use_kernel):
             raise AssertionError(f"request {r.rid}: bad tokens {g[:8]}...")
     toks = sum(len(r.generated) for r in done)
     print(f"serve (use_kernel={use_kernel}): {len(done)} requests, {toks} "
-          f"tokens, {eng.decode_steps} decode ticks, {eng.prefill_calls} "
-          f"prefill batches; smoke time {dt:.1f}s (compilation included)")
+          f"tokens, {eng.decode_steps} decode ticks ({eng.pool_in_place_ticks}"
+          f" pool_in_place_ticks), {eng.prefill_calls} prefill batches; smoke "
+          f"time {dt:.1f}s (compilation included)")
+    if eng.pool_in_place_ticks != eng.decode_steps:
+        raise AssertionError("a decode tick copied the page pool instead of "
+                             "updating it in place")
     return eng, {r.rid: list(r.generated) for r in done}
 
 

@@ -1,20 +1,26 @@
 """Paged split-KV decode attention (vLLM-style PagedAttention on TPU).
 
-Flash-decoding over a global page pool ``(P, page_size, Hkv, D)`` — the
-engine's layout, left as it is — instead of a dense per-request cache.
-The per-request block table arrives via scalar prefetch (SMEM) alongside
-lengths, and the K/V BlockSpec index_map dereferences it:
+Flash-decoding over a global page pool ``(L, P, page_size, Hkv, D)`` — the
+engine's stacked layout, left as it is — instead of a dense per-request
+cache.  The per-request block table arrives via scalar prefetch (SMEM)
+alongside lengths and the layer index, and the K/V BlockSpec index_map
+dereferences it:
 
-    grid step (b, j)  →  physical page  block_tables[b, j]
+    grid step (b, j)  →  page  (layer, block_tables[b, j])
 
 so the Pallas pipeline DMAs exactly the pages the request owns, in table
 order, with no host-side gather.  Scalar-prefetched operands are available
 to index_maps *before* the grid runs — that is what lets the DMA schedule
 itself be data-dependent (the whole point of paging: fragmentation-free
-allocation without ever materializing a dense copy).
+allocation without ever materializing a dense copy).  The layer index
+lets a decode step hand the kernel the whole stacked pool, which the step
+updates in place, instead of a slice of one layer.  A one-layer pool
+``(P, page_size, Hkv, D)`` is the ``L = 1``, ``layer = 0`` case, by a
+free reshape.
 
 Block layout (page-major).  The grid is ``(B, pages)``; each step DMAs one
-whole page of K and of V, block ``(1, page_size, Hkv, D)``.  The TPU
+whole page of K and of V, block ``(1, page_size, Hkv, D)`` of the layer
+(the layer axis is squeezed out of the block).  The TPU
 compiler requires a block's last two dims to be multiples of (8, 128) or
 equal to the array's, and ``(Hkv, D)`` equals the array's, so the block
 compiles at any head count — a per-head block ``(1, ps, 1, D)`` does not.
@@ -43,7 +49,7 @@ __all__ = ["paged_decode_attention_kernel"]
 NEG_INF = -1e30
 
 
-def _kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
+def _kernel(lengths_ref, tables_ref, layer_ref, q_ref, k_ref, v_ref, o_ref,
             acc_ref, m_ref, l_ref, *, sm_scale, page_size, n_kv_heads):
     b = pl.program_id(0)
     pi = pl.program_id(1)
@@ -86,37 +92,41 @@ def _kernel(lengths_ref, tables_ref, q_ref, k_ref, v_ref, o_ref,
 
 
 def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, lengths,
-                                  *, interpret: bool = False):
-    """q: (B, Hq, D); k/v_pages: (P, ps, Hkv, D); block_tables: (B, NP).
+                                  layer=0, *, interpret: bool = False):
+    """q: (B, Hq, D); k/v_pages: (L, P, ps, Hkv, D), or (P, ps, Hkv, D) for
+    one layer; block_tables: (B, NP); ``layer``: int32 scalar, the layer
+    of the pool to read.
 
     ``lengths``: (B,) int32 valid tokens (attends [0, lengths)); padded
     table entries must be valid page ids (they are skipped, not read).
     Returns (B, Hq, D) in q.dtype.
     """
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
     b, hq, d = q.shape
-    ps, hkv = k_pages.shape[1], k_pages.shape[2]
+    ps, hkv = k_pages.shape[2], k_pages.shape[3]
     npages = block_tables.shape[1]
     g = hq // hkv
     sm_scale = 1.0 / math.sqrt(d)
 
     qg = q.reshape(b, hkv, g, d)
 
-    def kv_map(b_, pi, lens, tabs):
-        return (tabs[b_, pi], 0, 0, 0)
+    def kv_map(b_, pi, lens, tabs, lay):
+        return (lay[0], tabs[b_, pi], 0, 0, 0)
 
-    def q_map(b_, pi, lens, tabs):
+    def q_map(b_, pi, lens, tabs, lay):
         return (b_, 0, 0, 0)
 
     out = pl.pallas_call(
         functools.partial(_kernel, sm_scale=sm_scale, page_size=ps,
                           n_kv_heads=hkv),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
+            num_scalar_prefetch=3,
             grid=(b, npages),
             in_specs=[
                 pl.BlockSpec((1, hkv, g, d), q_map),
-                pl.BlockSpec((1, ps, hkv, d), kv_map),
-                pl.BlockSpec((1, ps, hkv, d), kv_map),
+                pl.BlockSpec((None, 1, ps, hkv, d), kv_map),
+                pl.BlockSpec((None, 1, ps, hkv, d), kv_map),
             ],
             out_specs=pl.BlockSpec((1, hkv, g, d), q_map),
             scratch_shapes=[
@@ -131,5 +141,5 @@ def paged_decode_attention_kernel(q, k_pages, v_pages, block_tables, lengths,
         # from any other Pallas call in the same step
         name="paged_attention",
     )(lengths.astype(jnp.int32), block_tables.astype(jnp.int32),
-      qg, k_pages, v_pages)
+      jnp.reshape(layer, (1,)).astype(jnp.int32), qg, k_pages, v_pages)
     return out.reshape(b, hq, d)

@@ -15,14 +15,18 @@ from repro.kernels.decode_attention.ref import decode_ref
 __all__ = ["paged_decode_ref"]
 
 
-def paged_decode_ref(q, k_pages, v_pages, block_tables, lengths):
-    """q: (B, Hq, D); k/v_pages: (P, ps, Hkv, D); block_tables: (B, NP) int32.
+def paged_decode_ref(q, k_pages, v_pages, block_tables, lengths, layer=0):
+    """q: (B, Hq, D); k/v_pages: (L, P, ps, Hkv, D), or (P, ps, Hkv, D)
+    for one layer; block_tables: (B, NP) int32; ``layer``: the layer of a
+    stacked pool to read.
 
     ``lengths``: (B,) valid tokens per request (attends slots
     [0, lengths)); table entries past ``ceil(length/ps)`` are padding and
     may hold any valid page id — masking keeps them unread.  Returns
     (B, Hq, D) in q.dtype.
     """
+    if k_pages.ndim == 5:
+        k_pages, v_pages = k_pages[layer], v_pages[layer]
     b, np_ = block_tables.shape
     ps, hkv, d = k_pages.shape[1:]
     kd = k_pages[block_tables].reshape(b, np_ * ps, hkv, d)

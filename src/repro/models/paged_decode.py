@@ -80,22 +80,25 @@ def supports_paged_decode(cfg: ModelConfig) -> bool:
                for _name, kind, _n in _stack_names(cfg))
 
 
-def _paged_attention(p, cfg: ModelConfig, x, k_pages, v_pages, block_tables,
-                     lengths, active, *, use_kernel: bool, interpret: bool):
+def _paged_attention(p, cfg: ModelConfig, x, k_pool, v_pool, layer,
+                     block_tables, lengths, active, *, use_kernel: bool,
+                     interpret: bool):
     """One-token GQA attention over pages; mirrors ``decode_attention``.
 
-    ``x`` is (B, 1, d_model); ``k_pages``/``v_pages`` are one layer's
-    (P, page_size, Hkv, hd) physical pages (slot ``P - 1`` is the trash
-    page); ``block_tables`` is (B, max_pages) int32; ``lengths`` (B,)
-    int32; ``active`` (B,) bool.  The new token's KV is scattered into
-    the page backing logical position ``min(length, s_max - 1)`` for
-    active lanes (trash page otherwise), then attention reads positions
-    ``[0, length]`` through the registry's paged kernel/ref pair.
-    Returns ``(y, k_pages, v_pages)``.
+    ``x`` is (B, 1, d_model); ``k_pool``/``v_pool`` are the whole stacked
+    (L, P, page_size, Hkv, hd) physical pools (slot ``P - 1`` is the trash
+    page) and ``layer`` the int32 index of this layer in them;
+    ``block_tables`` is (B, max_pages) int32; ``lengths`` (B,) int32;
+    ``active`` (B,) bool.  The new token's KV is scattered into
+    ``[layer, page, offset]`` of the page backing logical position
+    ``min(length, s_max - 1)`` for active lanes (trash page otherwise),
+    then attention reads positions ``[0, length]`` of ``layer`` through
+    the registry's paged kernel/ref pair.  Returns ``(y, k_pool, v_pool)``:
+    the pools are updated in place, never sliced per layer.
     """
     cd = cfg.cdtype
     b = x.shape[0]
-    n_phys, ps = k_pages.shape[0], k_pages.shape[1]
+    n_phys, ps = k_pool.shape[1], k_pool.shape[2]
     s_max = block_tables.shape[1] * ps
     q, k_new, v_new = _project_qkv(p, cfg, x)
     if cfg.rope != "none":
@@ -111,8 +114,8 @@ def _paged_attention(p, cfg: ModelConfig, x, k_pages, v_pages, block_tables,
     logical = slot // ps
     phys = jnp.where(active, block_tables[jnp.arange(b), logical], n_phys - 1)
     off = slot % ps
-    k_pages = k_pages.at[phys, off].set(k_new[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[phys, off].set(v_new[:, 0].astype(v_pages.dtype))
+    k_pool = k_pool.at[layer, phys, off].set(k_new[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[layer, phys, off].set(v_new[:, 0].astype(v_pool.dtype))
 
     # Valid rows per lane: [0, length] inclusive of the token just written
     # (identical to the dense mask idx <= min(lengths, s_max-1)); inactive
@@ -120,26 +123,26 @@ def _paged_attention(p, cfg: ModelConfig, x, k_pages, v_pages, block_tables,
     att_len = jnp.where(active, jnp.minimum(lengths + 1, s_max), 0)
     out = registry.dispatch(
         "paged_decode_attention",
-        (q[:, 0], k_pages, v_pages, block_tables, att_len),
-        use_kernel=use_kernel, interpret=interpret)
+        (q[:, 0], k_pool, v_pool, block_tables, att_len),
+        common={"layer": layer}, use_kernel=use_kernel, interpret=interpret)
     out = out.reshape(b, 1, cfg.n_heads, cfg.hd)
     y = jnp.einsum("bshd,hdm->bsm", out.astype(cd), p["wo"].astype(cd),
                    preferred_element_type=jnp.float32).astype(cd)
-    return y, k_pages, v_pages
+    return y, k_pool, v_pool
 
 
-def _paged_block_decode(p, cfg: ModelConfig, kind: str, x, k_pages, v_pages,
-                        block_tables, lengths, active, *,
+def _paged_block_decode(p, cfg: ModelConfig, kind: str, x, k_pool, v_pool,
+                        layer, block_tables, lengths, active, *,
                         use_kernel: bool, interpret: bool):
     """One transformer block's decode step over paged KV (dense/moe only)."""
     h = apply_norm(cfg.norm, p["ln1"], x)
-    a, k_pages, v_pages = _paged_attention(
-        p["attn"], cfg, h, k_pages, v_pages, block_tables, lengths, active,
-        use_kernel=use_kernel, interpret=interpret)
+    a, k_pool, v_pool = _paged_attention(
+        p["attn"], cfg, h, k_pool, v_pool, layer, block_tables, lengths,
+        active, use_kernel=use_kernel, interpret=interpret)
     x = x + a
     h2 = apply_norm(cfg.norm, p["ln2"], x)
     y = moe(p["moe"], cfg, h2)[0] if kind == "moe" else mlp(p["mlp"], cfg, h2)
-    return x + y, k_pages, v_pages
+    return x + y, k_pool, v_pool
 
 
 def paged_decode_step(cfg: ModelConfig, params, token, cache: dict,
@@ -151,22 +154,26 @@ def paged_decode_step(cfg: ModelConfig, params, token, cache: dict,
     is ``{stack: {"k": (L, P, ps, Hkv, hd), "v": ...}}`` and
     ``block_tables`` (B, max_pages) int32 shared by every layer.  Returns
     ``(logits (B, V), new_cache)`` — the same contract as
-    :func:`~repro.models.transformer.decode_step`, over pages.
+    :func:`~repro.models.transformer.decode_step`, over pages.  Each
+    stack's pools ride the layer scan's carry, so under a jit that donates
+    ``cache`` the step writes its tokens into the pools in place.
     """
     x = _embed(cfg, params, token[:, None])
     new_caches = {}
-    for (name, kind, _n), (stacked, _k2, _n2) in zip(
+    for (name, kind, n), (stacked, _k2, _n2) in zip(
         _stack_names(cfg), _layer_stacks(cfg, params)
     ):
-        def body(h, inp, kind=kind):
-            lp, slc = inp
-            h, kp, vp = _paged_block_decode(
-                lp, cfg, kind, h, slc["k"], slc["v"], block_tables, lengths,
-                active, use_kernel=use_kernel, interpret=interpret)
-            return h, {"k": kp, "v": vp}
+        def body(carry, inp, kind=kind):
+            h, kp, vp = carry
+            lp, li = inp
+            return _paged_block_decode(
+                lp, cfg, kind, h, kp, vp, li, block_tables, lengths, active,
+                use_kernel=use_kernel, interpret=interpret), None
 
-        x, new_c = jax.lax.scan(body, x, (stacked, cache[name]))
-        new_caches[name] = new_c
+        (x, kp, vp), _ = jax.lax.scan(
+            body, (x, cache[name]["k"], cache[name]["v"]),
+            (stacked, jnp.arange(n, dtype=jnp.int32)))
+        new_caches[name] = {"k": kp, "v": vp}
     x = apply_norm(cfg.norm, params["final_norm"], x)
     logits = _head(cfg, params, x)[:, 0]
     return logits, new_caches

@@ -59,6 +59,7 @@ slot ``n_pages``) that no block table references.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from collections import OrderedDict
 from typing import Callable, Optional
 
@@ -75,7 +76,51 @@ from repro.models.paged_decode import (
 )
 from repro.serving.engine import InferenceEngine, KVPartition, StagedPrefill
 
-__all__ = ["PagedInferenceEngine", "PagedKVPool", "PagedKVView"]
+__all__ = ["PagedInferenceEngine", "PagedKVPool", "PagedKVView",
+           "paged_step_programs"]
+
+
+def paged_step_programs(arch, *, use_kernel: bool, interpret: bool):
+    """The paged engine's two decode programs, jitted with the page pools
+    (``cache``, argument 2) donated: ``_paged``, one decode step, and
+    ``_fused``, one decode step plus one chunk of a staged prefill.
+
+    Each consumes the pools it is given and returns them updated in
+    place (:func:`~repro.models.paged_decode.paged_decode_step` carries
+    them through its layer scan), so a caller must hold no pool array
+    across a call.
+    """
+    cfg = arch.cfg
+
+    @functools.partial(jax.jit, donate_argnums=(2,))
+    def _paged(params, token, cache, lengths, tables, active, temps, seeds):
+        logits, new_cache = paged_decode_step(
+            cfg, params, token, cache, tables, lengths, active,
+            use_kernel=use_kernel, interpret=interpret)
+        nxt = sample_tokens(logits, temps, seeds, lengths)
+        return nxt, new_cache
+
+    @functools.partial(jax.jit, donate_argnums=(2,))
+    def _fused(params, token, cache, lengths, tables, active,
+               temps, seeds, ctoks, ccache, clens):
+        # Chunk side: the same lax.scan of decode_step the standalone
+        # _extend performs, over the staged (dense, batch-1) cache —
+        # fused into ONE device program with the paged decode batch.
+        def step(carry, tok):
+            c, ln = carry
+            logits, c = arch.decode_step(params, tok, c, ln)
+            return (c, ln + 1), logits
+
+        (ccache, clens), clogits = jax.lax.scan(
+            step, (ccache, clens), jnp.swapaxes(ctoks, 0, 1))
+        cfirst = jnp.argmax(clogits[-1], axis=-1).astype(jnp.int32)
+        logits, new_cache = paged_decode_step(
+            cfg, params, token, cache, tables, lengths, active,
+            use_kernel=use_kernel, interpret=interpret)
+        nxt = sample_tokens(logits, temps, seeds, lengths)
+        return nxt, new_cache, cfirst, ccache, clens
+
+    return _paged, _fused
 
 
 class PagedKVPool:
@@ -476,6 +521,9 @@ class PagedInferenceEngine(InferenceEngine):
         self.on_lane_evicted: Optional[Callable] = None
         self.page_evictions = 0   # lanes evicted by page pressure
         self.fused_folds = 0      # prefill chunks folded into decode ticks
+        # Paged decode dispatches after which the previous pools read
+        # deleted: the runtime took the donation and updated them in place.
+        self.pool_in_place_ticks = 0
         self._fused_chunk: Optional[StagedPrefill] = None
         # Per-lane sampling params for the cross-template decode
         # megabatch: one dispatch covers every active lane, so the
@@ -518,40 +566,8 @@ class PagedInferenceEngine(InferenceEngine):
         self.cache = jax.tree_util.tree_map(pageify, self.cache)
         self._interpret = (self.interpret if self.interpret is not None
                            else registry.interpret_default())
-        cfg, uk, itp = self.arch.cfg, self.use_kernel, self._interpret
-
-        @jax.jit
-        def _paged(params, token, cache, lengths, tables, active,
-                   temps, seeds):
-            logits, new_cache = paged_decode_step(
-                cfg, params, token, cache, tables, lengths, active,
-                use_kernel=uk, interpret=itp)
-            nxt = sample_tokens(logits, temps, seeds, lengths)
-            return nxt, new_cache
-
-        self._paged_decode = _paged
-
-        @jax.jit
-        def _fused(params, token, cache, lengths, tables, active,
-                   temps, seeds, ctoks, ccache, clens):
-            # Chunk side: the same lax.scan of decode_step the standalone
-            # _extend performs, over the staged (dense, batch-1) cache —
-            # fused into ONE device program with the paged decode batch.
-            def step(carry, tok):
-                c, ln = carry
-                logits, c = self.arch.decode_step(params, tok, c, ln)
-                return (c, ln + 1), logits
-
-            (ccache, clens), clogits = jax.lax.scan(
-                step, (ccache, clens), jnp.swapaxes(ctoks, 0, 1))
-            cfirst = jnp.argmax(clogits[-1], axis=-1).astype(jnp.int32)
-            logits, new_cache = paged_decode_step(
-                cfg, params, token, cache, tables, lengths, active,
-                use_kernel=uk, interpret=itp)
-            nxt = sample_tokens(logits, temps, seeds, lengths)
-            return nxt, new_cache, cfirst, ccache, clens
-
-        self._fused = _fused
+        self._paged_decode, self._fused = paged_step_programs(
+            self.arch, use_kernel=self.use_kernel, interpret=self._interpret)
 
         max_len = self.max_len
 
@@ -949,6 +965,7 @@ class PagedInferenceEngine(InferenceEngine):
         if not self.paged_compute:
             return super()._dispatch_decode()
         part, tables, active_dev, temps, seeds = args
+        old_pools = jax.tree_util.tree_leaves(self.cache)
         if part is None:
             nxt, self.cache = self._paged_decode(
                 self.params, self.last_token, self.cache, self.lengths,
@@ -962,6 +979,8 @@ class PagedInferenceEngine(InferenceEngine):
             if not part.pending:
                 part.first = cfirst
             self.fused_folds += 1
+        if all(a.is_deleted() for a in old_pools):
+            self.pool_in_place_ticks += 1
         self._advance_lanes(nxt)
         return nxt
 

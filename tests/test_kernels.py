@@ -281,6 +281,25 @@ def test_paged_decode_attention_sweep(b, hq, hkv, np_, ps, d, dtype):
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32), rtol=rtol, atol=atol)
 
+    # The layer case: a stacked (3, P, ps, Hkv, D) pool whose layers hold
+    # different pages, read at each layer index.
+    kl = jnp.stack([k_pages, -k_pages, jnp.roll(k_pages, 1, axis=0)])
+    vl = jnp.stack([v_pages, jnp.roll(v_pages, 1, axis=0), 2 * v_pages])
+    kernel = jax.jit(lambda li: paged_decode_attention_kernel(
+        q, kl, vl, tables, lengths, li, interpret=True))
+    for layer in range(3):
+        li = jnp.int32(layer)
+        out = kernel(li)
+        ref = paged_decode_ref(q, kl, vl, tables, lengths, layer=li)
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   rtol=rtol, atol=atol)
+        if layer:  # each layer reads its own pages
+            assert not np.allclose(np.asarray(ref, np.float32),
+                                   np.asarray(paged_decode_ref(
+                                       q, kl, vl, tables, lengths, layer=0),
+                                       np.float32))
+
 
 def test_paged_decode_matches_dense_decode():
     """A paged cache whose tables are a permutation of a dense cache's

@@ -277,3 +277,108 @@ def test_paged_view_feeds_paged_kernel(setup):
     ref = decode_ref(q, k, v, view["lengths"])
     np.testing.assert_allclose(np.asarray(paged), np.asarray(ref),
                                rtol=2e-5, atol=2e-5)
+
+
+# ------------------------------------------------- pools updated in place
+
+def _watch_pools(eng) -> list:
+    """Record, for every decode dispatch of ``eng``, whether the pool
+    arrays it was handed read deleted afterwards (donated and reused)."""
+    seen = []
+    dispatch = eng._dispatch_decode
+
+    def watched(*args):
+        old = jax.tree_util.tree_leaves(eng.cache)
+        nxt = dispatch(*args)
+        seen.append(all(a.is_deleted() for a in old))
+        return nxt
+
+    eng._dispatch_decode = watched
+    return seen
+
+
+def _pool_run(arch, params, scenario, engine_cls, **kw):
+    """Drive one engine through ``scenario``; returns each request's
+    tokens.  The paged engine takes the scenario's path (fused chunk
+    tick, spill and restore, copy-on-write fork, prefix share); the dense
+    engine serves the same requests straight, as the oracle."""
+    rng = np.random.default_rng(53)
+    paged = engine_cls is PagedInferenceEngine
+    if scenario == "prefix_share":
+        prompts = [np.concatenate([rng.integers(1, 200, size=16),
+                                   rng.integers(1, 200, size=4)]
+                                  ).astype(np.int32) for _ in range(3)]
+        prompts[1][:16] = prompts[2][:16] = prompts[0][:16]
+    else:
+        prompts = [rng.integers(1, 200, size=n).astype(np.int32)
+                   for n in (6, 13)]
+    if paged:
+        kw.update(page_size=8, prefetch_pages=1,
+                  prefix_share=scenario == "prefix_share")
+    eng = engine_cls(arch, params, n_lanes=4, max_prompt_len=32, max_len=48,
+                     kv_spill=HostSpillPool(4), **kw)
+    seen = _watch_pools(eng) if paged else None
+    reqs = [Request(rid=i, prompt=p, max_new_tokens=12)
+            for i, p in enumerate(prompts)]
+
+    def tick(rs):
+        out = eng.decode_tick()
+        for r in rs:
+            r.generated.append(out[r.lane])
+
+    if scenario == "fused":
+        eng.admit(reqs[:1], None)
+        if paged:
+            staged = eng.prefill_dispatch(reqs[1:], template=None, chunk=4)
+            while not staged.complete:
+                assert eng.stage_chunk(staged)
+                tick(reqs[:1])
+            eng.commit_prefill(staged)
+            assert eng.fused_folds == 3
+        else:
+            for _ in range(3):  # the paged engine's three fused ticks
+                tick(reqs[:1])
+            eng.admit(reqs[1:], None)
+        for _ in range(3):
+            tick(reqs)
+    else:
+        eng.admit(reqs, None)
+        for _ in range(3):
+            tick(reqs)
+        if paged and scenario == "spill_restore":
+            r = reqs[1]
+            assert eng.spill(r.lane, r.rid, None)
+            r.lane = eng.try_restore(r.rid, None)
+            assert r.lane in eng._pending_restore  # tail flushed next tick
+        if paged and scenario == "cow_fork":
+            # alias every page of each lane to a ghost reader: a write
+            # into a shared page forks it first
+            for r in reqs:
+                n = len(eng.pool.pages(r.lane))
+                eng.pool.share(r.lane, ("ghost", r.rid), n_pages=n)
+        for _ in range(4):
+            tick(reqs)
+    if paged:
+        assert len(seen) == eng.decode_steps and all(seen)
+        assert eng.pool_in_place_ticks == eng.decode_steps
+        if scenario == "prefix_share":
+            assert eng.prefix_hits == 2
+        if scenario == "cow_fork":
+            # request 0 (length 9) wrote into its shared second page, and
+            # forked it first
+            ghost = eng.pool.pages(("ghost", 0))
+            assert eng.pool.pages(reqs[0].lane)[1] != ghost[1]
+    return [r.generated for r in reqs]
+
+
+@pytest.mark.parametrize("scenario", ["fused", "spill_restore", "cow_fork",
+                                      "prefix_share"])
+def test_paged_decode_updates_pool_in_place(setup, scenario):
+    """Every paged decode dispatch, a fused chunk tick included, consumes
+    the pools it is handed: they read deleted afterwards and
+    ``pool_in_place_ticks`` counts each tick.  Spill and restore, a
+    copy-on-write fork and prefix sharing around the donated step still
+    give the dense engine's tokens bit for bit."""
+    arch, params = setup
+    assert (_pool_run(arch, params, scenario, PagedInferenceEngine)
+            == _pool_run(arch, params, scenario, InferenceEngine))

@@ -1,5 +1,5 @@
-"""Main-path kernels compiled for a described TPU v5e (no chip needed),
-plus the compile-cache helper.
+"""Main-path kernels and the paged decode step compiled for a described
+TPU v5e (no chip needed), plus the compile-cache helper.
 
 The TPU compiler is installed with JAX and compiles for a topology that
 is described, not attached; it enforces what interpret mode does not
@@ -9,6 +9,7 @@ the worker running this file loads the TPU library.
 """
 from __future__ import annotations
 
+import dataclasses
 import re
 
 import jax
@@ -16,8 +17,11 @@ import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.kernels import registry
 from repro.kernels.paged_attention.kernel import paged_decode_attention_kernel
 from repro.launch import compile_cache
+from repro.models.registry import get_arch
+from repro.serving.paged_kv import paged_step_programs
 
 
 @pytest.fixture(scope="module")
@@ -75,6 +79,86 @@ def test_paged_kernel_compiles_for_v5e(one_chip, no_compile_cache, hq, hkv):
     assert "tpu_custom_call" in text
     # the kernel keeps its own name in the compiled program
     assert re.search(r"%paged_attention[.\d]* = .*tpu_custom_call", text)
+
+
+# ------------------------------------------------- paged decode step, in place
+
+_INSTR = re.compile(r"^\s*(?:ROOT )?%([\w.-]+) = (\S+) ([\w-]+)\(")
+_COMP = re.compile(r"^(?:ENTRY )?%([\w.-]+) .*\{$")
+_CALLS = re.compile(r"calls=%([\w.-]+)")
+
+
+def _pool_writers(text: str, pool_dims: list[str]) -> list[tuple]:
+    """Every instruction of the compiled ``text``, outside fused
+    computations, whose output is one of the bf16 arrays ``pool_dims``
+    (``"2,1025,16,16,128"``), bar parameters and tuple reads:
+    ``[(name, opcode, root opcode of the computation it calls)]``."""
+    shapes = tuple(f"bf16[{d}]" for d in pool_dims)
+    found, roots, fused, comp = [], {}, set(), None
+    for line in text.splitlines():
+        head = _COMP.match(line)
+        if head:
+            comp = head.group(1)
+            continue
+        m = _INSTR.match(line)
+        if not m:
+            continue
+        name, shape, op = m.groups()
+        called = _CALLS.search(line)
+        called = called.group(1) if called else None
+        if op == "fusion":
+            fused.add(called)
+        if line.lstrip().startswith("ROOT "):
+            roots[comp] = op
+        if (shape.split("{")[0] in shapes
+                and op not in ("parameter", "get-tuple-element")):
+            found.append((comp, name, op, called))
+    return [(name, op, roots.get(called))
+            for comp, name, op, called in found if comp not in fused]
+
+
+@pytest.mark.parametrize("hq,hkv", [
+    (16, 16),   # OLMo-1B: MHA
+    (32, 8),    # GQA, group of 4
+])
+def test_paged_decode_step_updates_pool_in_place(one_chip, no_compile_cache,
+                                                 monkeypatch, hq, hkv):
+    """The engine's paged decode program (``_paged``, cache donated) at
+    OLMo-1B widths, 2 layers, 8 lanes and the benchmark cell's 1025-page
+    pool: both pools alias input to output, the only instructions that
+    write a pool-shaped array are the two in-place token scatters (no
+    per-layer slice, restack or copy), and temporaries stay small."""
+    # the step dispatches the kernel as it does on a chip
+    monkeypatch.setattr(registry.jax, "default_backend", lambda: "tpu")
+    arch = get_arch("olmo-1b")
+    arch = dataclasses.replace(arch, cfg=dataclasses.replace(
+        arch.cfg, n_layers=2, n_heads=hq, n_kv_heads=hkv))
+    paged, _ = paged_step_programs(arch, use_kernel=True, interpret=False)
+    b, n_phys, ps, np_, d = 8, 1025, 16, 128, arch.cfg.hd
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(arch.init, jax.random.PRNGKey(0)))
+    pool = sds((2, n_phys, ps, hkv, d), jnp.bfloat16)
+    compiled = paged.lower(
+        params, sds((b,), jnp.int32), {"layers": {"k": pool, "v": pool}},
+        sds((b,), jnp.int32), sds((b, np_), jnp.int32),
+        sds((b,), jnp.bool_), sds((b,), jnp.float32), sds((b,), jnp.int32),
+    ).compile()
+
+    mem = compiled.memory_analysis()
+    pool_bytes = 2 * 2 * n_phys * ps * hkv * d * 2
+    assert mem.alias_size_in_bytes == pool_bytes
+    assert mem.temp_size_in_bytes < 64 * 2**20
+    writers = _pool_writers(compiled.as_text(),
+                            [f"2,{n_phys},{ps},{hkv},{d}",
+                             f"{n_phys},{ps},{hkv},{d}"])
+    assert len(writers) == 2, writers
+    for _name, op, root in writers:
+        assert "scatter" in (op, root), writers
 
 
 # ------------------------------------------------------------ compile cache

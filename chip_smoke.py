@@ -84,7 +84,8 @@ def serve_phase(arch, params, prompts, *, use_kernel):
     toks = sum(len(r.generated) for r in done)
     print(f"serve (use_kernel={use_kernel}): {len(done)} requests, {toks} "
           f"tokens, {eng.decode_steps} decode ticks ({eng.pool_in_place_ticks}"
-          f" pool_in_place_ticks), {eng.prefill_calls} prefill batches; smoke "
+          f" pool_in_place_ticks), {eng.prefill_calls} prefill batches "
+          f"({eng.prefill_attn_chunks} prefill_attn_chunks); smoke "
           f"time {dt:.1f}s (compilation included)")
     if eng.pool_in_place_ticks != eng.decode_steps:
         raise AssertionError("a decode tick copied the page pool instead of "

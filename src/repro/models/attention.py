@@ -28,7 +28,8 @@ from repro.models.layers import (
     dense_init,
 )
 
-__all__ = ["attn_params", "attention", "decode_attention", "init_kv_cache"]
+__all__ = ["attn_params", "attention", "decode_attention", "init_kv_cache",
+           "query_chunk"]
 
 NEG_INF = -1e30
 
@@ -80,6 +81,15 @@ def _rope(cfg: ModelConfig, q, k, positions):
     raise ValueError(cfg.rope)
 
 
+def query_chunk(cfg: ModelConfig, s: int) -> int:
+    """Query rows per chunk of full-sequence attention over ``s`` positions:
+    ``cfg.attn_chunk`` when that splits them, else 0 (one unchunked block,
+    whose scores are at most ``attn_chunk`` x ``s`` when chunking is on).
+    The chunks run are ``ceil(s / query_chunk)``; the last is padded."""
+    c = cfg.attn_chunk
+    return c if c and s > c else 0
+
+
 def _gqa_scores(q, k):
     """(B,S,H,hd) x (B,T,Hkv,hd) -> (B,Hkv,G,S,T) with G = H // Hkv."""
     b, s, h, hd = q.shape
@@ -120,19 +130,24 @@ def attention(
     if causal and kv_x is None:
         qpos = positions if positions.ndim == 2 else positions[0]
 
-    if cfg.attn_chunk and s > cfg.attn_chunk and s % cfg.attn_chunk == 0:
+    C = query_chunk(cfg, s)
+    if C:
         # Chunked (flash-style) scores: scan query blocks so the biggest
         # intermediate is (B,Hkv,G,C,S) instead of (B,Hkv,G,S,S).  Exact —
-        # softmax rows are independent.
-        C = cfg.attn_chunk
+        # softmax rows are independent, so the query rows are padded up to
+        # whole chunks and the padded rows' output is dropped; the keys
+        # stay as they are.
         g = h // hkv
-        qg = q.reshape(b, s // C, C, hkv, g, hd)
-        qc = jnp.moveaxis(qg, 1, 0)  # (nc, b, C, hkv, g, hd)
-        pc = (
-            jnp.moveaxis(qpos.reshape(b, s // C, C), 1, 0)
-            if qpos is not None else jnp.zeros((s // C, b, C), jnp.int32)
-        )
-        kpos = qpos if qpos is not None else None
+        n = -(-s // C)
+        pad = n * C - s
+        qg = jnp.pad(q, ((0, 0), (0, pad), (0, 0), (0, 0))) if pad else q
+        qc = jnp.moveaxis(qg.reshape(b, n, C, hkv, g, hd), 1, 0)
+        if qpos is not None:
+            pp = jnp.pad(qpos, ((0, 0), (0, pad))) if pad else qpos
+            pc = jnp.moveaxis(pp.reshape(b, n, C), 1, 0)
+        else:
+            pc = jnp.zeros((n, b, C), jnp.int32)
+        kpos = qpos
 
         def chunk_fn(_, inp):
             q_blk, p_blk = inp  # (b,C,hkv,g,hd), (b,C)
@@ -150,7 +165,9 @@ def attention(
             return _, o.reshape(b, q_blk.shape[1], h, hd)
 
         _, out_c = jax.lax.scan(chunk_fn, 0, (qc, pc))
-        out = jnp.moveaxis(out_c, 0, 1).reshape(b, s, h, hd)
+        out = jnp.moveaxis(out_c, 0, 1).reshape(b, n * C, h, hd)
+        if pad:
+            out = out[:, :s]
     else:
         scores = _gqa_scores(q, k)  # (B,Hkv,G,S,T)
         if qpos is not None:

@@ -84,6 +84,10 @@ class ModelConfig:
     # modality frontend stub: the backbone consumes precomputed embeddings
     frontend: str = "none"  # none | patch_stub | audio_stub
 
+    # RMSNorm's epsilon, as the model publishes it (the LayerNorms keep
+    # their own 1e-5)
+    norm_eps: float = 1e-6
+
     # numerics
     param_dtype: str = "float32"
     compute_dtype: str = "float32"
@@ -97,7 +101,8 @@ class ModelConfig:
 
     # chunked (flash-style) attention: bound the score materialization to
     # (B, H, attn_chunk, S) by scanning query blocks — exact same math,
-    # O(S·chunk) memory instead of O(S²).  0 = off (one-shot scores).
+    # O(S·chunk) memory instead of O(S²), at any S (the last block is
+    # padded).  0 = off (one-shot scores).
     attn_chunk: int = 0
 
     # ----------------------------------------------------------------- derived

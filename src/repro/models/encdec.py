@@ -75,23 +75,23 @@ def encode(cfg: ModelConfig, params, src_embeds):
     x = shard_activation(x, "dp", None, None)
 
     def body(h, lp):
-        hn = apply_norm(cfg.norm, lp["ln1"], h)
+        hn = apply_norm(cfg.norm, lp["ln1"], h, cfg.norm_eps)
         h = h + attention(lp["attn"], cfg, hn, pos, causal=False)
-        hn = apply_norm(cfg.norm, lp["ln2"], h)
+        hn = apply_norm(cfg.norm, lp["ln2"], h, cfg.norm_eps)
         return h + mlp(lp["mlp"], cfg, hn), None
 
     if cfg.remat:
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["enc_layers"])
-    return apply_norm(cfg.norm, params["enc_norm"], x)
+    return apply_norm(cfg.norm, params["enc_norm"], x, cfg.norm_eps)
 
 
 def _dec_block(lp, cfg, x, pos, memory):
-    hn = apply_norm(cfg.norm, lp["ln1"], x)
+    hn = apply_norm(cfg.norm, lp["ln1"], x, cfg.norm_eps)
     x = x + attention(lp["attn"], cfg, hn, pos, causal=True)
-    hn = apply_norm(cfg.norm, lp["ln_cross"], x)
+    hn = apply_norm(cfg.norm, lp["ln_cross"], x, cfg.norm_eps)
     x = x + attention(lp["cross_attn"], cfg, hn, pos, causal=False, kv_x=memory)
-    hn = apply_norm(cfg.norm, lp["ln2"], x)
+    hn = apply_norm(cfg.norm, lp["ln2"], x, cfg.norm_eps)
     return x + mlp(lp["mlp"], cfg, hn)
 
 
@@ -108,7 +108,7 @@ def forward_encdec(cfg: ModelConfig, params, src_embeds, tgt_tokens):
     if cfg.remat:
         body = jax.checkpoint(body)
     x, _ = jax.lax.scan(body, x, params["layers"])
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     logits = jnp.einsum("bsd,dv->bsv", x, params["lm_head"]["w"].astype(cfg.cdtype),
                         preferred_element_type=jnp.float32)
     return shard_activation(logits, "dp", None, "model"), jnp.float32(0.0)
@@ -133,14 +133,14 @@ def prefill_encdec(cfg: ModelConfig, params, src_embeds, tgt_tokens,
     pos = jnp.broadcast_to(jnp.arange(S, dtype=jnp.int32)[None], (B, S))
 
     def body(h, lp):
-        hn = apply_norm(cfg.norm, lp["ln1"], h)
+        hn = apply_norm(cfg.norm, lp["ln1"], h, cfg.norm_eps)
         a, (k, v) = attention(lp["attn"], cfg, hn, pos, causal=True, return_kv=True)
         h = h + a
-        hn = apply_norm(cfg.norm, lp["ln_cross"], h)
+        hn = apply_norm(cfg.norm, lp["ln_cross"], h, cfg.norm_eps)
         c, (ck, cv) = attention(lp["cross_attn"], cfg, hn, pos, causal=False,
                                 kv_x=memory, return_kv=True)
         h = h + c
-        hn = apply_norm(cfg.norm, lp["ln2"], h)
+        hn = apply_norm(cfg.norm, lp["ln2"], h, cfg.norm_eps)
         return h + mlp(lp["mlp"], cfg, hn), {"k": k, "v": v, "cross_k": ck, "cross_v": cv}
 
     x, cache = jax.lax.scan(body, x, params["layers"])
@@ -148,7 +148,7 @@ def prefill_encdec(cfg: ModelConfig, params, src_embeds, tgt_tokens,
         pad = max_len - S
         cache["k"] = jnp.pad(cache["k"], ((0, 0),) * 2 + ((0, pad),) + ((0, 0),) * 2)
         cache["v"] = jnp.pad(cache["v"], ((0, 0),) * 2 + ((0, pad),) + ((0, 0),) * 2)
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     logits = jnp.einsum("bd,dv->bv", x[:, -1], params["lm_head"]["w"].astype(cfg.cdtype),
                         preferred_element_type=jnp.float32)
     return logits, cache
@@ -160,14 +160,14 @@ def decode_step_encdec(cfg: ModelConfig, params, token, cache, lengths):
 
     def body(h, inp):
         lp, ck, cv, xk, xv = inp
-        hn = apply_norm(cfg.norm, lp["ln1"], h)
+        hn = apply_norm(cfg.norm, lp["ln1"], h, cfg.norm_eps)
         a, nk, nv = decode_attention(lp["attn"], cfg, hn, ck, cv, lengths)
         h = h + a
-        hn = apply_norm(cfg.norm, lp["ln_cross"], h)
+        hn = apply_norm(cfg.norm, lp["ln_cross"], h, cfg.norm_eps)
         # cross-attention against fixed memory KV (no cache update)
         c = _cross_decode(lp["cross_attn"], cfg, hn, xk, xv)
         h = h + c
-        hn = apply_norm(cfg.norm, lp["ln2"], h)
+        hn = apply_norm(cfg.norm, lp["ln2"], h, cfg.norm_eps)
         return h + mlp(lp["mlp"], cfg, hn), (nk, nv)
 
     x, (new_k, new_v) = jax.lax.scan(
@@ -175,7 +175,7 @@ def decode_step_encdec(cfg: ModelConfig, params, token, cache, lengths):
         (params["layers"], cache["k"], cache["v"], cache["cross_k"], cache["cross_v"]),
     )
     new_cache = dict(cache, k=new_k, v=new_v)
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     logits = jnp.einsum("bd,dv->bv", x[:, 0], params["lm_head"]["w"].astype(cfg.cdtype),
                         preferred_element_type=jnp.float32)
     return logits, new_cache

@@ -96,9 +96,11 @@ def norm_params(kind: str, dim: int, dtype):
     raise ValueError(kind)
 
 
-def apply_norm(kind: str, p, x):
+def apply_norm(kind: str, p, x, eps: float = 1e-6):
+    """The norm ``kind`` of ``x``; ``eps`` is RMSNorm's (the LayerNorms
+    keep their own 1e-5)."""
     if kind == "rmsnorm":
-        return rmsnorm(x, p["w"])
+        return rmsnorm(x, p["w"], eps)
     if kind == "layernorm":
         return layernorm(x, p["w"], p["b"])
     if kind == "nonparam_ln":
@@ -140,7 +142,15 @@ def apply_rope(q, k, positions, theta: float):
 
 def apply_rope_half(q, k, positions, theta: float):
     """ChatGLM's 2D RoPE: rotary on the first half of the head dim only
-    [arXiv:2406.12793 / GLM lineage]."""
+    [arXiv:2406.12793 / GLM lineage].
+
+    The rotated half pairs channel i with i + d/4, as :func:`apply_rope`
+    does over a whole head.  ChatGLM's own code rotates interleaved pairs
+    (2i, 2i + 1) of that half instead; the two are the same function once
+    the rotated channels of ``wq``, ``wk``, ``bq`` and ``bk`` are permuted
+    (evens first, then odds), so weights drawn here, or converted with that
+    permutation, serve ChatGLM exactly.
+    """
     d = q.shape[-1]
     rot = d // 2
     q1, q2 = q[..., :rot], q[..., rot:]

@@ -135,12 +135,12 @@ def _paged_block_decode(p, cfg: ModelConfig, kind: str, x, k_pool, v_pool,
                         layer, block_tables, lengths, active, *,
                         use_kernel: bool, interpret: bool):
     """One transformer block's decode step over paged KV (dense/moe only)."""
-    h = apply_norm(cfg.norm, p["ln1"], x)
+    h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     a, k_pool, v_pool = _paged_attention(
         p["attn"], cfg, h, k_pool, v_pool, layer, block_tables, lengths,
         active, use_kernel=use_kernel, interpret=interpret)
     x = x + a
-    h2 = apply_norm(cfg.norm, p["ln2"], x)
+    h2 = apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     y = moe(p["moe"], cfg, h2)[0] if kind == "moe" else mlp(p["mlp"], cfg, h2)
     return x + y, k_pool, v_pool
 
@@ -174,6 +174,6 @@ def paged_decode_step(cfg: ModelConfig, params, token, cache: dict,
             body, (x, cache[name]["k"], cache[name]["v"]),
             (stacked, jnp.arange(n, dtype=jnp.int32)))
         new_caches[name] = {"k": kp, "v": vp}
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     logits = _head(cfg, params, x)[:, 0]
     return logits, new_caches

@@ -88,10 +88,10 @@ def _block_forward(p, cfg: ModelConfig, kind: str, x, positions):
     """Full-sequence block (training / prefill w/o cache).  → (x, aux)."""
     aux = jnp.float32(0.0)
     if kind == "ssm":
-        h = apply_norm(cfg.norm, p["ln1"], x)
+        h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
         x = x + ssm_forward(p["ssm"], cfg, h)
         return x, aux
-    h = apply_norm(cfg.norm, p["ln1"], x)
+    h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     if kind == "hybrid":
         # Hymba [arXiv:2411.13676]: attention and SSM heads in parallel on
         # the same input; per-branch RMSNorm, then mean.
@@ -102,7 +102,7 @@ def _block_forward(p, cfg: ModelConfig, kind: str, x, positions):
         x = x + 0.5 * (a + s)
     else:
         x = x + attention(p["attn"], cfg, h, positions, causal=True)
-    h2 = apply_norm(cfg.norm, p["ln2"], x)
+    h2 = apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     if kind == "moe":
         y, aux = moe(p["moe"], cfg, h2)
     else:
@@ -117,10 +117,10 @@ def _block_prefill(p, cfg: ModelConfig, kind: str, x, positions):
     kv = None
     ssm_state = None
     if kind == "ssm":
-        h = apply_norm(cfg.norm, p["ln1"], x)
+        h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
         y, ssm_state = ssm_forward(p["ssm"], cfg, h, return_state=True)
         return x + y, aux, kv, ssm_state
-    h = apply_norm(cfg.norm, p["ln1"], x)
+    h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     if kind == "hybrid":
         a, kv = attention(p["attn"], cfg, h, positions, causal=True, return_kv=True)
         s, ssm_state = ssm_forward(p["ssm"], cfg, h, return_state=True)
@@ -130,7 +130,7 @@ def _block_prefill(p, cfg: ModelConfig, kind: str, x, positions):
     else:
         a, kv = attention(p["attn"], cfg, h, positions, causal=True, return_kv=True)
         x = x + a
-    h2 = apply_norm(cfg.norm, p["ln2"], x)
+    h2 = apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     y, aux = (moe(p["moe"], cfg, h2) if kind == "moe" else (mlp(p["mlp"], cfg, h2), aux))
     return x + y, aux, kv, ssm_state
 
@@ -139,11 +139,11 @@ def _block_decode(p, cfg: ModelConfig, kind: str, x, cache_slice, lengths):
     """One-token decode.  cache_slice: per-layer dict of cache arrays."""
     new_cache = dict(cache_slice)
     if kind == "ssm":
-        h = apply_norm(cfg.norm, p["ln1"], x)
+        h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
         y, s, c = ssm_decode_step(p["ssm"], cfg, h, cache_slice["ssm"], cache_slice["conv"])
         new_cache["ssm"], new_cache["conv"] = s, c
         return x + y, new_cache
-    h = apply_norm(cfg.norm, p["ln1"], x)
+    h = apply_norm(cfg.norm, p["ln1"], x, cfg.norm_eps)
     window = cfg.attn_window if cfg.attn_window > 0 else None
     if kind == "hybrid":
         a, ck, cv = decode_attention(
@@ -160,7 +160,7 @@ def _block_decode(p, cfg: ModelConfig, kind: str, x, cache_slice, lengths):
         )
         x = x + a
         new_cache.update(k=ck, v=cv)
-    h2 = apply_norm(cfg.norm, p["ln2"], x)
+    h2 = apply_norm(cfg.norm, p["ln2"], x, cfg.norm_eps)
     y = moe(p["moe"], cfg, h2)[0] if kind == "moe" else mlp(p["mlp"], cfg, h2)
     return x + y, new_cache
 
@@ -277,7 +277,7 @@ def forward(cfg: ModelConfig, params, tokens=None, positions=None, embeds=None):
     for stacked, kind, _n in _layer_stacks(cfg, params):
         x, aux, _ = _run_stack(cfg, stacked, kind, x, positions, "forward")
         aux_total = aux_total + aux
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     return _head(cfg, params, x), aux_total
 
 
@@ -348,7 +348,7 @@ def prefill(cfg: ModelConfig, params, tokens=None, positions=None, embeds=None,
                 c["k"] = jnp.pad(c["k"], ((0, 0),) * 2 + ((0, pad),) + ((0, 0),) * 2)
                 c["v"] = jnp.pad(c["v"], ((0, 0),) * 2 + ((0, pad),) + ((0, 0),) * 2)
         caches[name] = c
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     if return_all_logits:
         return _head(cfg, params, x), caches
     logits = _head(cfg, params, x[:, -1:])[:, 0]
@@ -366,6 +366,6 @@ def decode_step(cfg: ModelConfig, params, token, cache: dict, lengths):
             cfg, stacked, kind, x, None, "decode", cache=cache[name], lengths=lengths
         )
         new_caches[name] = new_c
-    x = apply_norm(cfg.norm, params["final_norm"], x)
+    x = apply_norm(cfg.norm, params["final_norm"], x, cfg.norm_eps)
     logits = _head(cfg, params, x)[:, 0]
     return logits, new_caches

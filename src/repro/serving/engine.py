@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.models.attention import query_chunk
 from repro.models.registry import Arch
 from repro.serving.tracing import span
 
@@ -414,6 +415,9 @@ class InferenceEngine:
         # every prefill dispatch; 1 - real / bucket is the padding share.
         self.prefill_real_tokens = 0
         self.prefill_bucket_tokens = 0
+        # Query chunks of the prefill's full-sequence attention, per
+        # layer, summed over prefill dispatches (0 while unchunked).
+        self.prefill_attn_chunks = 0
         # template -> pinned (batch, prompt) prefill bucket: each template
         # converges on ONE compiled prefill shape (monotone max of what it
         # has needed), so a template burst stops recompiling per batch size.
@@ -472,6 +476,18 @@ class InferenceEngine:
             self.prefill_real_tokens += int(np.sum(staged.plens[:n]))
             self.prefill_bucket_tokens += staged.shape[0] * staged.shape[1]
         return staged
+
+    def _prefill_span(self, requests: Sequence, shape: tuple, real: list):
+        """The ``engine.prefill`` span of one padded prefill dispatch of
+        ``shape`` holding ``real`` prompt tokens in its rows, with the
+        query rows per attention chunk it runs (``attn_chunk``, 0 for
+        unchunked); counts its chunks in :attr:`prefill_attn_chunks`."""
+        chunk = query_chunk(self.arch.cfg, shape[1])
+        if chunk:
+            with self._dispatch_lock:
+                self.prefill_attn_chunks += -(-shape[1] // chunk)
+        return span("engine.prefill", rids=[r.rid for r in requests],
+                    shape=shape, real=real, attn_chunk=chunk)
 
     def _host(self, x, copy: bool = False) -> np.ndarray:
         """``x`` in host memory, a writable copy with ``copy``: one
@@ -561,8 +577,8 @@ class InferenceEngine:
             bsz = max(bsz, pinned[0])
             plen = max(plen, pinned[1])
             self.template_shapes[template] = (bsz, plen)
-        with span("engine.prefill", rids=[r.rid for r in requests],
-                  shape=(bsz, plen)):
+        with self._prefill_span(requests, (bsz, plen),
+                                [len(p) for p in prompts]):
             toks = np.zeros((bsz, plen), np.int32)
             plens = np.ones((bsz,), np.int32)
             for i, p in enumerate(prompts):
@@ -591,7 +607,7 @@ class InferenceEngine:
         the template's pinned batch bucket."""
         S = len(prompt)
         c0 = min(chunk, S)
-        with span("engine.prefill", rids=[r.rid], shape=(1, c0)):
+        with self._prefill_span([r], (1, c0), [c0]):
             first, cache = self._prefill(
                 self.params, jnp.asarray(prompt[None, :c0]),
                 jnp.asarray([c0], jnp.int32), self.max_len)

@@ -80,6 +80,11 @@ __all__ = ["PagedInferenceEngine", "PagedKVPool", "PagedKVView",
            "paged_step_programs"]
 
 
+# XLA's memory-space assignment on a TPU: no prefetch that moves more
+# bytes than its uses read (a pool staged whole into VMEM for one page).
+MSA_OPTIONS = {"xla_tpu_msa_inefficient_use_to_copy_ratio": 1.0}
+
+
 def paged_step_programs(arch, *, use_kernel: bool, interpret: bool):
     """The paged engine's two decode programs, jitted with the page pools
     (``cache``, argument 2) donated: ``_paged``, one decode step, and
@@ -89,10 +94,21 @@ def paged_step_programs(arch, *, use_kernel: bool, interpret: bool):
     place (:func:`~repro.models.paged_decode.paged_decode_step` carries
     them through its layer scan), so a caller must hold no pool array
     across a call.
+
+    On a TPU both compile with ``MSA_OPTIONS``: a stacked pool that fits
+    in VMEM (ChatGLM3-6B's two kv heads: 59 MB a pool at 14 layers) is
+    otherwise staged there and back around every layer's token scatter
+    and kernel call, a whole-pool copy each way per layer.  Where no pool
+    fits (OLMo-1B's cell) the compiled programs are the same with or
+    without the option.
     """
     cfg = arch.cfg
+    jit = functools.partial(
+        jax.jit, donate_argnums=(2,),
+        compiler_options=MSA_OPTIONS if jax.default_backend() == "tpu"
+        else None)
 
-    @functools.partial(jax.jit, donate_argnums=(2,))
+    @jit
     def _paged(params, token, cache, lengths, tables, active, temps, seeds):
         logits, new_cache = paged_decode_step(
             cfg, params, token, cache, tables, lengths, active,
@@ -100,7 +116,7 @@ def paged_step_programs(arch, *, use_kernel: bool, interpret: bool):
         nxt = sample_tokens(logits, temps, seeds, lengths)
         return nxt, new_cache
 
-    @functools.partial(jax.jit, donate_argnums=(2,))
+    @jit
     def _fused(params, token, cache, lengths, tables, active,
                temps, seeds, ctoks, ccache, clens):
         # Chunk side: the same lax.scan of decode_step the standalone

@@ -1,5 +1,5 @@
-"""Main-path kernels and the paged decode step compiled for a described
-TPU v5e (no chip needed), plus the compile-cache helper.
+"""Main-path kernels, the paged decode step and the padded prefill compiled
+for a described TPU v5e (no chip needed), plus the compile-cache helper.
 
 The TPU compiler is installed with JAX and compiles for a topology that
 is described, not attached; it enforces what interpret mode does not
@@ -20,8 +20,9 @@ from jax.sharding import SingleDeviceSharding
 from repro.kernels import registry
 from repro.kernels.paged_attention.kernel import paged_decode_attention_kernel
 from repro.launch import compile_cache
+from repro.models.attention import query_chunk
 from repro.models.registry import get_arch
-from repro.serving.paged_kv import paged_step_programs
+from repro.serving.paged_kv import PagedInferenceEngine, paged_step_programs
 
 
 @pytest.fixture(scope="module")
@@ -57,6 +58,7 @@ def no_compile_cache():
 @pytest.mark.parametrize("hq,hkv", [
     (16, 16),   # OLMo-1B: MHA
     (32, 8),    # GQA, group of 4
+    (32, 2),    # ChatGLM3-6B: GQA, group of 16
 ])
 def test_paged_kernel_compiles_for_v5e(one_chip, no_compile_cache, hq, hkv):
     """The paged decode kernel at OLMo-1B widths (B=8, D=128, page 16,
@@ -120,6 +122,7 @@ def _pool_writers(text: str, pool_dims: list[str]) -> list[tuple]:
 @pytest.mark.parametrize("hq,hkv", [
     (16, 16),   # OLMo-1B: MHA
     (32, 8),    # GQA, group of 4
+    (32, 2),    # ChatGLM3-6B: GQA, group of 16
 ])
 def test_paged_decode_step_updates_pool_in_place(one_chip, no_compile_cache,
                                                  monkeypatch, hq, hkv):
@@ -159,6 +162,42 @@ def test_paged_decode_step_updates_pool_in_place(one_chip, no_compile_cache,
     assert len(writers) == 2, writers
     for _name, op, root in writers:
         assert "scatter" in (op, root), writers
+
+
+# ------------------------------------------------- prefill, chunked attention
+
+@pytest.mark.parametrize("chunk", [
+    496,        # the chatglm3-6b.pp2.long-prompt-sat cell's: 8 chunks of 3968
+    512,        # does not divide 3968: the last chunk is padded
+])
+def test_chatglm_prefill_attention_runs_chunked(one_chip, no_compile_cache,
+                                                chunk):
+    """The engine's padded prefill program (``_prefill``) at ChatGLM3-6B
+    widths, 2 layers, the benchmark cell's pinned bucket (2, 3968) and
+    ``attn_chunk`` ``chunk``: its temporaries stay under the float32
+    all-position logits plus one layer's full (B, Hkv, G, S, S) float32
+    scores.  The unchunked program holds both (8.1 GB); the chunked one
+    holds the logits and one chunk's scores (4.1 GB)."""
+    arch = get_arch("chatglm3-6b")
+    arch = dataclasses.replace(arch, cfg=dataclasses.replace(
+        arch.cfg, n_layers=2, attn_chunk=chunk))
+    cfg = arch.cfg
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(arch.init, jax.random.PRNGKey(0)))
+    b, s = 2, 3968
+    eng = PagedInferenceEngine(arch, params, n_lanes=b, max_prompt_len=s,
+                               max_len=4096, page_size=16)
+    compiled = eng._prefill.lower(params, sds((b, s), jnp.int32),
+                                  sds((b,), jnp.int32), 4096).compile()
+    logits = b * s * cfg.vocab_size * 4
+    scores = b * cfg.n_heads * s * s * 4
+    assert query_chunk(cfg, s) == chunk
+    assert compiled.memory_analysis().temp_size_in_bytes < logits + scores
 
 
 # ------------------------------------------------------------ compile cache

@@ -161,6 +161,7 @@ def test_one_tick_yields_the_span_tree(setup):
     assert admit[3] <= dec[2] and dec[3] <= tokens[2]
     assert pre[5]["rids"] == com[5]["rids"] == [0, 1]
     assert pre[5]["shape"] == (2, 16)
+    assert pre[5]["real"] == [5, 11] and pre[5]["attn_chunk"] == 0
     assert list(dec[5]["lanes"]) == [0, 1]
 
 
@@ -199,6 +200,32 @@ def test_prefill_token_counters_sum_each_staged_prefill(setup):
     assert eng.prefill_real_tokens == real == 3 + 16 + 23 + 14 + 25
     assert eng.prefill_bucket_tokens == bucket
     assert bucket > real     # the pinned "chat" bucket pads
+
+
+def test_prefill_spans_and_counter_say_how_attention_was_chunked(setup):
+    """With ``attn_chunk`` on, each ``engine.prefill`` span carries the
+    real prompt tokens of its rows and the query rows per attention chunk
+    it ran, and ``prefill_attn_chunks`` counts the chunks per layer: a
+    16-row bucket at chunk 6 runs 3 (the last padded), a 5-token first
+    chunk of a chunked prefill none."""
+    arch, params = setup
+    arch = dataclasses.replace(arch, cfg=dataclasses.replace(
+        arch.cfg, attn_chunk=6))
+    eng = _engine((arch, params))
+    tracing.enable(True)
+    eng.prefill_dispatch(_requests([9, 13], rid0=0), "chat")
+    eng.prefill_dispatch(_requests([4], rid0=2), "chat")
+    eng.prefill_dispatch(_requests([14], rid0=3), None, chunk=5)
+    eng.prefill_dispatch(_requests([12], rid0=4), None, chunk=8)
+    pre = _by_name(tracing.records())["engine.prefill"]
+    assert [(p[5]["shape"], p[5]["real"], p[5]["attn_chunk"])
+            for p in pre] == [((2, 16), [9, 13], 6), ((2, 16), [4], 6),
+                              ((1, 5), [5], 0), ((1, 8), [8], 6)]
+    assert eng.prefill_attn_chunks == 3 + 3 + 0 + 2
+    # the count does not depend on tracing
+    tracing.enable(False)
+    eng.prefill_dispatch(_requests([3], rid0=5), "chat")
+    assert eng.prefill_attn_chunks == 11
 
 
 # ------------------------------------------------------------ compile log

@@ -82,10 +82,12 @@ def serve_phase(arch, params, prompts, *, use_kernel):
         if len(g) != MAX_NEW or min(g) < 0 or max(g) >= arch.cfg.vocab_size:
             raise AssertionError(f"request {r.rid}: bad tokens {g[:8]}...")
     toks = sum(len(r.generated) for r in done)
+    rungs = dict(sorted(eng.prefill_rung_programs.items()))
     print(f"serve (use_kernel={use_kernel}): {len(done)} requests, {toks} "
           f"tokens, {eng.decode_steps} decode ticks ({eng.pool_in_place_ticks}"
           f" pool_in_place_ticks), {eng.prefill_calls} prefill batches "
-          f"({eng.prefill_attn_chunks} prefill_attn_chunks); smoke "
+          f"({eng.prefill_attn_chunks} prefill_attn_chunks, "
+          f"prefill_rung_programs {rungs}); smoke "
           f"time {dt:.1f}s (compilation included)")
     if eng.pool_in_place_ticks != eng.decode_steps:
         raise AssertionError("a decode tick copied the page pool instead of "

@@ -47,7 +47,14 @@ Two further mechanisms extend the split dispatch path:
   restores the rows into a fresh lane (:meth:`InferenceEngine.try_restore`)
   and decode continues where it stopped.
 
-Prefill batches are padded to power-of-two buckets (bounded jit cache).
+**Prefill ladder** (``prefill_dispatch(..., template=t)``).  Each prompt
+runs as its own ``(1, rung)`` prefill program, the rung being its
+power-of-two length bucket between :data:`RUNG_FLOOR` and
+``max_prompt_len``; a batch's programs are enqueued back to back under one
+aggregate :class:`StagedPrefill`.  The template's first dispatch runs
+every rung once on zeros and its first commit compiles their inserts, so
+steady traffic compiles nothing.  Dispatches without a template pad one
+batch to power-of-two buckets.
 """
 from __future__ import annotations
 
@@ -62,6 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.attention import query_chunk
+from repro.models.paged_decode import supports_paged_decode
 from repro.models.registry import Arch
 from repro.serving.tracing import span
 
@@ -69,6 +77,14 @@ __all__ = ["HostSpillPool", "InferenceEngine", "KVPartition", "StagedPrefill",
            "proportional_shares"]
 
 _SHARED = "__shared__"  # KVPartition pool key for unreserved lanes
+
+# The prefill ladder's shortest rung.  A v5e computes 197 TFLOP/s in bf16
+# and streams 819 GB/s, a ridge point of about 240 FLOP a byte: below about
+# 240 rows a bf16 prefill is bound by streaming its weights, so fewer rows
+# save no time.  From 256 rows up it is bound by compute, so k one-prompt
+# programs cost what one k-row program costs, and a prompt pays only for
+# its own rung.  A property of the chip, not a setting.
+RUNG_FLOOR = 256
 
 
 def _bucket(n: int) -> int:
@@ -411,17 +427,33 @@ class InferenceEngine:
         # waits for the device (:meth:`_host`): a paged decode tick reads
         # one length per active lane, then its new tokens.
         self.host_reads = 0
-        # Real prompt tokens and padded bucket slots (batch x prompt) of
-        # every prefill dispatch; 1 - real / bucket is the padding share.
+        # Real prompt tokens and padded bucket slots (batch x prompt, summed
+        # over an aggregate's parts) of every prefill dispatch; 1 - real /
+        # bucket is the padding share.
         self.prefill_real_tokens = 0
         self.prefill_bucket_tokens = 0
+        # rung -> (1, rung) ladder programs dispatched.
+        self.prefill_rung_programs: dict[int, int] = {}
         # Query chunks of the prefill's full-sequence attention, per
         # layer, summed over prefill dispatches (0 while unchunked).
         self.prefill_attn_chunks = 0
-        # template -> pinned (batch, prompt) prefill bucket: each template
-        # converges on ONE compiled prefill shape (monotone max of what it
-        # has needed), so a template burst stops recompiling per batch size.
+        # template -> monotone max of the (batch bucket, largest rung) its
+        # ladder dispatches spanned: a record of the widest batch a
+        # template has sent (warm-ups size page inserts from it); no
+        # prefill dispatches it.
         self.template_shapes: dict[str, tuple[int, int]] = {}
+        # Right padding leaves a prompt's logits and KV exact only where
+        # every layer attends over the full causal context: windowed ring
+        # buffers and recurrent state take in the pad tokens, so there the
+        # ladder pads no further than the prompt's power-of-two bucket.
+        self._rung_floor = (RUNG_FLOOR if supports_paged_decode(self.arch.cfg)
+                            else 1)
+        # Set once the template path's first dispatch has run every rung
+        # program on zeros; the staged KV of the last of those runs waits
+        # for the first commit to compile the inserts (on the thread that
+        # owns the cache, which a decode step may be donating meanwhile).
+        self._rungs_warm = False
+        self._warm_src = None
 
         @partial(jax.jit, static_argnums=())
         def _decode(params, token, cache, lengths):
@@ -464,17 +496,23 @@ class InferenceEngine:
 
         self._extend = _extend
 
-    def _count_dispatch(self, n: int = 1) -> None:
-        """Record ``n`` jitted model-step dispatches (thread-safe)."""
+    def _count_dispatch(self, n: int = 1, rung: Optional[int] = None) -> None:
+        """Record ``n`` jitted model-step dispatches, one of them a ladder
+        program of ``rung`` if given (thread-safe)."""
         with self._dispatch_lock:
             self.dispatches += n
+            if rung is not None:
+                self.prefill_rung_programs[rung] = (
+                    self.prefill_rung_programs.get(rung, 0) + 1)
 
     def _count_prefill(self, staged: StagedPrefill, n: int) -> StagedPrefill:
         """Record the real and bucket tokens of a prefill dispatch of
-        ``n`` requests (thread-safe: the speculative thread dispatches)."""
+        ``n`` requests (thread-safe: the speculative thread dispatches).
+        An aggregate's bucket is the sum of its parts'."""
         with self._dispatch_lock:
             self.prefill_real_tokens += int(np.sum(staged.plens[:n]))
-            self.prefill_bucket_tokens += staged.shape[0] * staged.shape[1]
+            self.prefill_bucket_tokens += sum(
+                p.shape[0] * p.shape[1] for p in staged.parts or [staged])
         return staged
 
     def _prefill_span(self, requests: Sequence, shape: tuple, real: list):
@@ -498,17 +536,15 @@ class InferenceEngine:
     # ------------------------------------------------------------- admission
     def admit(self, requests: Sequence, template: Optional[str] = None
               ) -> tuple[int, int]:
-        """Prefill ``requests`` as ONE padded batch and insert into lanes.
+        """Prefill ``requests`` and insert them into lanes.
 
-        One prefill call for k requests is the set-oriented execution: one
-        device dispatch amortized over the batch (vs k single dispatches) —
-        the serving analogue of the paper's batched query.
-
-        ``template`` keys the padding bucket to the lane: the batch/prompt
-        bucket is pinned per template (monotone max), so every admission of
-        a template after its first dispatches the SAME compiled shape.
-        With ``kv_shares``, ``template`` also selects which lane pools the
-        batch may draw from (:meth:`n_free_for` bounds the batch size).
+        One call for k requests is the set-oriented execution: the batch
+        is dispatched and committed as one unit — the serving analogue of
+        the paper's batched query.  With ``template`` each prompt runs at
+        its own ladder rung (:meth:`prefill_dispatch`); without, the batch
+        is one padded program.  With ``kv_shares``, ``template`` also
+        selects which lane pools the batch may draw from
+        (:meth:`n_free_for` bounds the batch size).
 
         Equivalent to :meth:`prefill_dispatch` immediately followed by
         :meth:`commit_prefill` — the synchronous path, paying the prefill
@@ -523,16 +559,20 @@ class InferenceEngine:
     def prefill_dispatch(self, requests: Sequence,
                          template: Optional[str] = None,
                          chunk: Optional[int] = None) -> StagedPrefill:
-        """Dispatch (but do not commit) one padded prefill batch.
+        """Dispatch (but do not commit) the prefill of ``requests``.
 
-        Builds the padded token batch and issues the jitted prefill — an
-        *asynchronous* device dispatch: the call returns as soon as the
-        computation is enqueued, so the caller can overlap it with a decode
-        tick and commit at the next tick boundary.  No engine or request
-        state is mutated (the only write is the per-template shape pin,
-        a GIL-atomic dict store), so this is safe to call from the
-        scheduler's speculative-dispatch thread while :meth:`decode_tick`
-        runs on the main thread, and an uncommitted result can be dropped.
+        Issues the jitted prefill — an *asynchronous* device dispatch: the
+        call returns as soon as the computation is enqueued, so the caller
+        can overlap it with a decode tick and commit at the next tick
+        boundary.  No engine or request state is mutated (the writes are
+        counters and the :attr:`template_shapes` record), so this is safe
+        to call from the scheduler's speculative-dispatch thread while
+        :meth:`decode_tick` runs on the main thread, and an uncommitted
+        result can be dropped.
+
+        With ``template``, each prompt runs as its own ``(1, rung)``
+        program (:meth:`_ladder_dispatch`); without, the batch runs as one
+        program padded to power-of-two (batch, prompt) buckets.
 
         ``chunk`` enables resumable chunked prefill for ONE oversized
         prompt (the scheduler dispatches such prompts alone): the first
@@ -565,18 +605,13 @@ class InferenceEngine:
                     np.concatenate([pt.plens for pt in parts]),
                     (len(requests), int(max(len(p) for p in cprompts))),
                     parts=parts), len(requests))
+        if template is not None:
+            return self._ladder_dispatch(requests, template)
         bsz = _bucket(len(requests))
-        # Bucket the prompt axis to the batch's longest (truncated) prompt:
-        # lane-homogeneous admission (scheduler groups by template) means
-        # short-prompt classes prefill at e.g. 8 wide instead of always
-        # max_prompt_len — right-padding + causal mask keeps logits exact.
+        # Bucket the prompt axis to the batch's longest (truncated) prompt;
+        # right-padding + causal mask keeps logits exact.
         prompts = [r.prompt[-self.max_prompt_len:] for r in requests]
         plen = min(self.max_prompt_len, _bucket(max(len(p) for p in prompts)))
-        if template is not None:
-            pinned = self.template_shapes.get(template, (1, 1))
-            bsz = max(bsz, pinned[0])
-            plen = max(plen, pinned[1])
-            self.template_shapes[template] = (bsz, plen)
         with self._prefill_span(requests, (bsz, plen),
                                 [len(p) for p in prompts]):
             toks = np.zeros((bsz, plen), np.int32)
@@ -592,6 +627,74 @@ class InferenceEngine:
             StagedPrefill(template, list(requests), first, cache, plens,
                           (bsz, plen)), len(requests))
 
+    def _rung(self, n: int) -> int:
+        """The ladder rung of an ``n``-token prompt: its power-of-two
+        bucket, at least the floor, at most ``max_prompt_len``."""
+        return min(self.max_prompt_len, max(self._rung_floor, _bucket(n)))
+
+    def prefill_rungs(self) -> list[int]:
+        """Every rung a prompt of up to ``max_prompt_len`` tokens takes."""
+        return sorted({self._rung(n) for n in range(1, self.max_prompt_len + 1)})
+
+    def _ladder_dispatch(self, requests: Sequence,
+                         template: str) -> StagedPrefill:
+        """One ``(1, rung)`` prefill program per prompt, enqueued back to
+        back with no host read between them.
+
+        One prompt gives a plain staged prefill of shape ``(1, rung)``;
+        k prompts give an aggregate whose ``parts`` are the k single-prompt
+        stagings (the form the chunked path commits) and whose ``shape``
+        is ``(k, largest rung)``.  Each program opens its own
+        ``engine.prefill`` span and counts in :attr:`prefill_rung_programs`.
+        """
+        self._warm_rungs()
+        parts = []
+        for r in requests:
+            p = np.asarray(r.prompt[-self.max_prompt_len:], np.int32)
+            rung = self._rung(len(p))
+            toks = np.zeros((1, rung), np.int32)
+            toks[0, : len(p)] = p  # right-pad; causal mask hides pad keys
+            plens = np.asarray([len(p)], np.int32)
+            with self._prefill_span([r], (1, rung), [len(p)]):
+                first, cache = self._prefill(
+                    self.params, jnp.asarray(toks), jnp.asarray(plens),
+                    self.max_len)
+            self._count_dispatch(rung=rung)
+            parts.append(StagedPrefill(template, [r], first, cache, plens,
+                                       (1, rung)))
+        shape = (len(parts), max(pt.shape[1] for pt in parts))
+        pinned = self.template_shapes.get(template, (1, 1))
+        self.template_shapes[template] = (max(pinned[0], _bucket(shape[0])),
+                                          max(pinned[1], shape[1]))
+        if len(parts) == 1:
+            return self._count_prefill(parts[0], 1)
+        return self._count_prefill(StagedPrefill(
+            template, list(requests), None, None,
+            np.concatenate([pt.plens for pt in parts]), shape, parts=parts),
+            len(parts))
+
+    def _warm_rungs(self) -> None:
+        """Run every rung program once on zeros, once per engine: under
+        the dispatch lock, as the scheduler's overlap mode dispatches from
+        a second thread, and outside every counter and span.  The first
+        commit compiles the inserts from the staged KV kept here."""
+        if self._rungs_warm:
+            return
+        with self._dispatch_lock:
+            if self._rungs_warm:
+                return
+            for rung in self.prefill_rungs():
+                _, cache = self._prefill(
+                    self.params, jnp.zeros((1, rung), jnp.int32),
+                    jnp.ones((1,), jnp.int32), self.max_len)
+            self._warm_src = cache
+            self._rungs_warm = True
+
+    def _warm_inserts(self, src) -> None:
+        """Compile the commit's insert of a ladder program's staged KV
+        ``src`` without writing any lane (the result is dropped)."""
+        _insert_lanes(self.cache, src, [0])
+
     def _chunked_dispatch(self, r, prompt: np.ndarray,
                           template: Optional[str], chunk: int) -> StagedPrefill:
         """Chunked-path dispatch: prefill the first chunk, stage the rest.
@@ -601,10 +704,9 @@ class InferenceEngine:
         ``chunk..S-1``), so the committed KV matches what a one-shot
         prefill of the full prompt would have produced.  A prompt that
         fits one chunk degenerates to a batch-1 one-shot (complete
-        immediately) so batched-chunk parents may mix sizes.  The
-        per-template shape pin is NOT consulted: chunk shapes are their
-        own (bounded) compile family, and a huge prompt must not widen
-        the template's pinned batch bucket."""
+        immediately) so batched-chunk parents may mix sizes.  Chunk
+        shapes are their own (bounded) compile family, apart from the
+        ladder's rungs."""
         S = len(prompt)
         c0 = min(chunk, S)
         with self._prefill_span([r], (1, c0), [c0]):
@@ -680,6 +782,9 @@ class InferenceEngine:
             "commit_prefill() beyond this template's free lanes"
         if not reqs:
             return staged.shape
+        if self._warm_src is not None:
+            src, self._warm_src = self._warm_src, None
+            self._warm_inserts(src)
         with span("engine.commit", rids=[r.rid for r in reqs]):
             first = self._host(staged.first)  # waits for the async dispatch
             lanes = [self.partition.alloc(staged.template) for _ in reqs]

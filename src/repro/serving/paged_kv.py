@@ -860,9 +860,9 @@ class PagedInferenceEngine(InferenceEngine):
         """Page-granular commit splice.
 
         Paged-compute: per-request tables are opened (never evicting a
-        batch-mate) and exactly the pages each prompt fills are scattered
-        into physical frames.  Dense-compute keeps PR 6's bucket-max row
-        splice into the per-lane cache.
+        batch-mate) and the pages each prompt fills are scattered into
+        physical frames (:meth:`_scatter_prompt`).  Dense-compute keeps
+        the bucket-max row splice into the per-lane cache.
         """
         ps = self.page_size
         if not self.paged_compute:
@@ -900,19 +900,38 @@ class PagedInferenceEngine(InferenceEngine):
                 self.prefix_index.insert(
                     lane, np.asarray(r.prompt)[-plen:])
             npg = max(1, self.pool.pages_for(plen))
-            n_rows = npg * ps
-            idx = jnp.asarray(self.pool.pages(lane)[:npg])
-
-            def one(dst, src, i=i, idx=idx, npg=npg, n_rows=n_rows):
-                s = src[:, i, :n_rows]
-                return dst.at[:, idx].set(
-                    s.reshape(s.shape[0], npg, ps, *s.shape[2:])
-                    .astype(dst.dtype))
-
-            self.cache = jax.tree_util.tree_map(one, self.cache, staged.cache)
+            self._scatter_prompt(staged.cache, i, self.pool.pages(lane)[:npg],
+                                 staged.shape[1])
             for a in jax.tree_util.tree_leaves(staged.cache):
                 self.kv_bytes_moved += (a.dtype.itemsize * a.shape[0]
-                                        * n_rows * int(np.prod(a.shape[3:])))
+                                        * npg * ps * int(np.prod(a.shape[3:])))
+
+    def _scatter_prompt(self, src, i: int, pages, width: int) -> None:
+        """Scatter row ``i`` of the staged KV ``src`` into ``pages``.
+
+        The scatter covers the ``ceil(width / page_size)`` pages that the
+        staged program's prompt axis (``width``) spans, the pages past
+        ``pages`` going to the trash page, so the insert compiles once per
+        staged shape and not once per page count."""
+        ps = self.page_size
+        n = self.pool.pages_for(width)
+        idx = jnp.asarray(np.asarray(
+            list(pages) + [self.n_pages] * (n - len(pages)), np.int32))
+
+        def one(dst, s):
+            s = s[:, i, : n * ps]
+            return dst.at[:, idx].set(
+                s.reshape(s.shape[0], n, ps, *s.shape[2:]).astype(dst.dtype))
+
+        self.cache = jax.tree_util.tree_map(one, self.cache, src)
+
+    def _warm_inserts(self, src) -> None:
+        """Compile the page insert of every rung: ``src`` scattered whole
+        into the trash page.  Dense-compute mode splices rows per page
+        count and compiles as prompts come."""
+        if self.paged_compute:
+            for rung in self.prefill_rungs():
+                self._scatter_prompt(src, 0, [], rung)
 
     # ------------------------------------------------------- fused dispatch
     def stage_chunk(self, staged: StagedPrefill) -> bool:

@@ -23,18 +23,17 @@ paper's strategies transfer verbatim:
 
 Like the sharded :class:`~repro.core.runtime.AsyncQueryRuntime`, pending
 requests are held in one lane per :attr:`Request.template`: each admission
-batch is drawn from a single template's lane (homogeneous prompts bucket
-tighter in the padded prefill), and mixed traffic classes stop head-of-line
-blocking each other.  The strategy is consulted per lane; admission
-round-robins over lanes while engine slots remain free.
+batch is drawn from a single template's lane, and mixed traffic classes
+stop head-of-line blocking each other.  The strategy is consulted per
+lane; admission round-robins over lanes while engine slots remain free.
 
 With a :class:`~repro.core.lane_policy.LanePolicy` (``policy=``), each
 template lane is asked its OWN strategy (hot templates learn a per-lane
 AdaptiveCost model, cold ones stay pure-async), lanes are visited in
 weighted-fair order instead of round-robin, and both prefill (admit) and
 decode-tick durations feed back into that lane's cost model.  Admission
-also passes the template to :meth:`InferenceEngine.admit`, which pins one
-compiled prefill shape per template and — with ``kv_shares`` — bounds the
+also passes the template to :meth:`InferenceEngine.admit`, which prefills
+each prompt at its own length rung and — with ``kv_shares`` — bounds the
 batch by that template's reserved + shared KV lanes
 (:meth:`InferenceEngine.n_free_for`), so a burst on one template cannot
 evict or starve the others' cache residency.
@@ -59,7 +58,7 @@ becomes a two-stage pipeline:
 The scheduler peeks (without popping — :meth:`ReadyLanes.peek`) the next
 ready lane, sizes a batch against the lanes that are free now *plus* the
 lanes decode is about to retire (the speculation), and dispatches its
-padded prefill on a separate thread through
+prefill on a separate thread through
 :meth:`InferenceEngine.prefill_dispatch` while :meth:`decode_tick` runs.
 At the next tick boundary the staged KV is committed into lanes
 (:meth:`InferenceEngine.commit_prefill`).  If the bet missed — the lanes

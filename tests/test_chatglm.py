@@ -4,7 +4,7 @@ and compared with the benchmark's plain float32 reference.
 The model has ChatGLM's kinds at small widths: 32 query heads over 2 kv
 heads (G = 16), head 16, QKV bias, rotary on the first half of each head,
 RMSNorm and an untied head.  ``attn_chunk`` 12 does not divide the 32-row
-prefill bucket, so the prefill's attention runs three chunks, the last
+prefill rung, so each prefill's attention runs three chunks, the last
 one padded.  Also here: the chunked attention equals the unchunked one at
 lengths that are not a multiple of the chunk, and the program's half-dim
 rotary equals ChatGLM's interleaved one under a fixed permutation of the
@@ -48,7 +48,7 @@ MODEL = {"n_layers": 2, "d_model": 64, "n_heads": 32, "n_kv_heads": 2,
          "rope_theta": 10000.0, "qkv_bias": True, "tie_embeddings": False,
          "dtype": "float32"}
 CHUNK = 12
-PROMPTS = (27, 13)      # one padded (2, 32) bucket
+PROMPTS = (27, 13)      # one (1, 32) rung program each
 MAX_NEW = 10            # the first token from the prefill, 9 decode steps
 # Both sides compute in float32 (the reference at HIGHEST), so only the
 # order of summation differs: about 2e-6 of the logits' scale here.  The
@@ -123,24 +123,25 @@ def test_paged_engine_matches_the_float32_reference(weights, use_kernel):
     arch = _arch()
     eng, reqs, steps = _serve(arch, weights, use_kernel)
     assert eng.template_shapes["glm"] == (2, 32)
-    assert eng.prefill_attn_chunks == 3          # ceil(32 / 12), one dispatch
+    assert eng.prefill_rung_programs == {32: 2}
+    assert eng.prefill_attn_chunks == 2 * 3      # ceil(32 / 12) a prompt
     assert len(steps) == MAX_NEW - 1
     for r in reqs:
         assert len(r.generated) == MAX_NEW
     seqs = [np.concatenate([r.prompt, r.generated]).astype(np.int32)
             for r in reqs]
 
-    # prefill: all-position logits of the engine's pinned bucket, as its
-    # _prefill computes them, at each prompt's last token
-    toks = np.zeros((2, 32), np.int32)
-    for i, r in enumerate(reqs):
-        toks[i, : len(r.prompt)] = r.prompt
-    pre, _ = transformer.prefill(arch.cfg, weights, tokens=jnp.asarray(toks),
-                                 max_len=64, return_all_logits=True)
+    # prefill: all-position logits of each prompt's rung program, as the
+    # engine's _prefill computes them, at the prompt's last token
     for i, r in enumerate(reqs):
         n = len(r.prompt)
+        toks = np.zeros((1, 32), np.int32)
+        toks[0, :n] = r.prompt
+        pre, _ = transformer.prefill(arch.cfg, weights,
+                                     tokens=jnp.asarray(toks), max_len=64,
+                                     return_all_logits=True)
         ref = _reference(weights, seqs[i], [n - 1])[0]
-        got = np.asarray(pre[i, n - 1])
+        got = np.asarray(pre[0, n - 1])
         scale = np.abs(ref).max()
         assert np.abs(got - ref).max() <= LOGIT_TOL * scale
         assert r.generated[0] == int(np.argmax(got))

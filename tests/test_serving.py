@@ -857,25 +857,45 @@ def test_prefetch_loader_order_and_bound():
     np.testing.assert_array_equal(batches[4]["tokens"], stream.batch_at(4)["tokens"])
 
 
-def test_engine_pins_one_prefill_shape_per_template(setup):
-    """template= admission pins the padding bucket: after a template's
-    largest batch, every later admit dispatches the SAME compiled shape."""
+@pytest.mark.parametrize("lengths", [(3,), (300, 20), (600, 257, 9),
+                                     (1000, 256, 40, 513, 2)])
+def test_engine_pins_one_prefill_shape_per_template(setup, lengths):
+    """template= dispatch runs each prompt as its own (1, rung) program,
+    the rung its power-of-two bucket between 256 and max_prompt_len; a
+    batch's parent shape is (k, largest rung); ``template_shapes`` keeps
+    the monotone max of (batch bucket, largest rung) it has dispatched."""
     arch, params = setup
-    eng = InferenceEngine(arch, params, n_lanes=8, max_prompt_len=16, max_len=48)
-    rng = np.random.default_rng(7)
-    reqs = _requests(6, rng, max_new=2)
-    shape_a = eng.admit(reqs[:3], template="chat")     # bucket (4, plen)
-    assert shape_a[0] == 4
-    for r in reqs[:3]:
-        eng.retire(r.lane)
-    shape_b = eng.admit(reqs[3:4], template="chat")    # 1 request, pinned shape
-    assert shape_b == shape_a
-    assert eng.template_shapes["chat"] == shape_a
-    for r in reqs[3:4]:
-        eng.retire(r.lane)
-    # an unrelated template sizes its own bucket from scratch
-    shape_c = eng.admit(reqs[4:5], template="embed")
-    assert shape_c[0] == 1
+    eng = InferenceEngine(arch, params, n_lanes=8, max_prompt_len=1000,
+                          max_len=1024)
+    assert eng.prefill_rungs() == [256, 512, 1000]
+
+    def rung(n):
+        return min(1000, max(256, 1 << (n - 1).bit_length()))
+
+    def reqs(ns, rid0=0):
+        rng = np.random.default_rng(rid0)
+        return [Request(rid=rid0 + i,
+                        prompt=rng.integers(1, 200, size=n).astype(np.int32),
+                        max_new_tokens=2) for i, n in enumerate(ns)]
+
+    staged = eng.prefill_dispatch(reqs(lengths), template="chat")
+    parts = staged.parts or [staged]
+    assert [p.shape for p in parts] == [(1, rung(n)) for n in lengths]
+    assert [int(p.plens[0]) for p in parts] == list(lengths)
+    top = max(rung(n) for n in lengths)
+    assert staged.shape == (len(lengths), top)
+    record = (1 << (len(lengths) - 1).bit_length(), top)
+    assert eng.template_shapes["chat"] == record
+    assert eng.prefill_rung_programs == {
+        r: sum(rung(n) == r for n in lengths) for r in {rung(n) for n in lengths}}
+    # a later, smaller batch runs at its own rung and leaves the record
+    one = eng.prefill_dispatch(reqs([5], rid0=10), template="chat")
+    assert one.shape == (1, 256) and not one.parts
+    assert eng.template_shapes["chat"] == record
+    assert eng.admit(reqs([5], rid0=11), template="chat") == (1, 256)
+    # an unrelated template's record starts from scratch
+    eng.prefill_dispatch(reqs(lengths[:1], rid0=20), template="embed")
+    assert eng.template_shapes["embed"] == (1, rung(lengths[0]))
 
 
 # ---------------------------------------------------------------------------

@@ -173,7 +173,8 @@ def test_paged_decode_step_updates_pool_in_place(one_chip, no_compile_cache,
 def test_chatglm_prefill_attention_runs_chunked(one_chip, no_compile_cache,
                                                 chunk):
     """The engine's padded prefill program (``_prefill``) at ChatGLM3-6B
-    widths, 2 layers, the benchmark cell's pinned bucket (2, 3968) and
+    widths, 2 layers, the (2, 3968) batch the benchmark cell pinned
+    before the prefill ladder (whose largest program is (1, 3968)) and
     ``attn_chunk`` ``chunk``: its temporaries stay under the float32
     all-position logits plus one layer's full (B, Hkv, G, S, S) float32
     scores.  The unchunked program holds both (8.1 GB); the chunked one

@@ -138,7 +138,7 @@ def test_one_tick_yields_the_span_tree(setup):
     (tick,) = recs["sched.tick"]
     (admit,) = recs["sched.admit"]
     (tokens,) = recs["sched.tokens"]
-    (pre,), (com,) = recs["engine.prefill"], recs["engine.commit"]
+    pres, coms = recs["engine.prefill"], recs["engine.commit"]
     (dec,) = recs["engine.decode_tick"]
     steps = [recs[f"engine.decode.{s}"][0]
              for s in ("prepare", "dispatch", "readback")]
@@ -149,8 +149,8 @@ def test_one_tick_yields_the_span_tree(setup):
 
     assert tick[1] is None
     inside(admit, tick)
-    inside(pre, admit)
-    inside(com, admit)
+    for sp in pres + coms:
+        inside(sp, admit)
     inside(dec, tick)
     inside(tokens, tick)
     for s in steps:
@@ -159,9 +159,12 @@ def test_one_tick_yields_the_span_tree(setup):
     assert [s[2] for s in steps] == sorted(s[2] for s in steps)
     assert steps[0][3] <= steps[1][2] and steps[1][3] <= steps[2][2]
     assert admit[3] <= dec[2] and dec[3] <= tokens[2]
-    assert pre[5]["rids"] == com[5]["rids"] == [0, 1]
-    assert pre[5]["shape"] == (2, 16)
-    assert pre[5]["real"] == [5, 11] and pre[5]["attn_chunk"] == 0
+    # one ladder program, and one commit, per prompt
+    assert [p[5]["rids"] for p in pres] == [c[5]["rids"] for c in coms] \
+        == [[0], [1]]
+    assert [p[5]["shape"] for p in pres] == [(1, 16), (1, 16)]
+    assert [p[5]["real"] for p in pres] == [[5], [11]]
+    assert [p[5]["attn_chunk"] for p in pres] == [0, 0]
     assert list(dec[5]["lanes"]) == [0, 1]
 
 
@@ -196,10 +199,14 @@ def test_prefill_token_counters_sum_each_staged_prefill(setup):
                               chunk=5), 3),
     ]
     real = sum(int(np.sum(s.plens[:n])) for s, n in staged)
-    bucket = sum(s.shape[0] * s.shape[1] for s, _ in staged)
+    # an aggregate counts its parts: "chat" pads each prompt to its rung
+    # (16, the prompt cap), the chunked parts are their prompts' lengths
+    bucket = sum(p.shape[0] * p.shape[1]
+                 for s, _ in staged for p in s.parts or [s])
     assert eng.prefill_real_tokens == real == 3 + 16 + 23 + 14 + 25
-    assert eng.prefill_bucket_tokens == bucket
-    assert bucket > real     # the pinned "chat" bucket pads
+    assert eng.prefill_bucket_tokens == bucket \
+        == 16 + 3 * 16 + 2 * 16 + 14 + (12 + 10 + 3)
+    assert bucket > real
 
 
 def test_prefill_spans_and_counter_say_how_attention_was_chunked(setup):
@@ -207,7 +214,8 @@ def test_prefill_spans_and_counter_say_how_attention_was_chunked(setup):
     real prompt tokens of its rows and the query rows per attention chunk
     it ran, and ``prefill_attn_chunks`` counts the chunks per layer: a
     16-row bucket at chunk 6 runs 3 (the last padded), a 5-token first
-    chunk of a chunked prefill none."""
+    chunk of a chunked prefill none.  Each "chat" prompt is its own
+    ladder program at rung 16."""
     arch, params = setup
     arch = dataclasses.replace(arch, cfg=dataclasses.replace(
         arch.cfg, attn_chunk=6))
@@ -219,13 +227,14 @@ def test_prefill_spans_and_counter_say_how_attention_was_chunked(setup):
     eng.prefill_dispatch(_requests([12], rid0=4), None, chunk=8)
     pre = _by_name(tracing.records())["engine.prefill"]
     assert [(p[5]["shape"], p[5]["real"], p[5]["attn_chunk"])
-            for p in pre] == [((2, 16), [9, 13], 6), ((2, 16), [4], 6),
-                              ((1, 5), [5], 0), ((1, 8), [8], 6)]
-    assert eng.prefill_attn_chunks == 3 + 3 + 0 + 2
+            for p in pre] == [((1, 16), [9], 6), ((1, 16), [13], 6),
+                              ((1, 16), [4], 6), ((1, 5), [5], 0),
+                              ((1, 8), [8], 6)]
+    assert eng.prefill_attn_chunks == 3 + 3 + 3 + 0 + 2
     # the count does not depend on tracing
     tracing.enable(False)
     eng.prefill_dispatch(_requests([3], rid0=5), "chat")
-    assert eng.prefill_attn_chunks == 11
+    assert eng.prefill_attn_chunks == 14
 
 
 # ------------------------------------------------------------ compile log

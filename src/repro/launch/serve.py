@@ -3,7 +3,9 @@
 Requests go through :class:`ContinuousBatchingScheduler` (admission under
 one of the paper's strategies) into a :class:`PagedInferenceEngine`,
 whose decode ticks run the Pallas ``paged_decode_attention`` kernel over
-the shared page pool (compiled on TPU; the jnp reference on CPU).
+the shared page pool (compiled on TPU; the jnp reference on CPU).  An
+architecture paged decode cannot run (sliding-window, recurrent or
+encoder-decoder layers) is served by the dense :class:`InferenceEngine`.
 
     PYTHONPATH=src python -m repro.launch.serve --arch olmo-1b --reduced \
         --requests 32 --strategy growing_upper
@@ -23,7 +25,9 @@ import numpy as np
 
 from repro.core.strategies import from_name
 from repro.launch.compile_cache import setup_compile_cache
+from repro.models.paged_decode import supports_paged_decode
 from repro.models.registry import Arch, get_arch
+from repro.serving.engine import InferenceEngine
 from repro.serving.paged_kv import PagedInferenceEngine
 from repro.serving.request import Request
 from repro.serving.scheduler import ContinuousBatchingScheduler
@@ -39,16 +43,24 @@ def serve_requests(arch: Arch, params, requests: Sequence[Request], *,
                    strategy: str = "growing_upper",
                    lane_timeout: Optional[int] = None,
                    use_kernel: bool = True):
-    """Build the paged engine and its scheduler, serve ``requests`` until
+    """Build the engine and its scheduler, serve ``requests`` until
     drained, and return ``(engine, scheduler, finished requests)``.
 
-    The scheduler runs without a failure domain (``resilience=None``), so
-    a device fault propagates instead of being retried away.
+    The engine is the :class:`PagedInferenceEngine` where
+    :func:`~repro.models.paged_decode.supports_paged_decode` accepts
+    ``arch``, and the dense :class:`InferenceEngine` otherwise (which
+    ignores ``page_size`` and ``use_kernel``).  The scheduler runs without
+    a failure domain (``resilience=None``), so a device fault propagates
+    instead of being retried away.
     """
-    eng = PagedInferenceEngine(arch, params, n_lanes=lanes,
-                               max_prompt_len=max_prompt_len,
-                               max_len=max_len, page_size=page_size,
-                               use_kernel=use_kernel)
+    if supports_paged_decode(arch.cfg):
+        eng = PagedInferenceEngine(arch, params, n_lanes=lanes,
+                                   max_prompt_len=max_prompt_len,
+                                   max_len=max_len, page_size=page_size,
+                                   use_kernel=use_kernel)
+    else:
+        eng = InferenceEngine(arch, params, n_lanes=lanes,
+                              max_prompt_len=max_prompt_len, max_len=max_len)
     kw = {"initial_upper": 2} if strategy == "growing_upper" else {}
     sched = ContinuousBatchingScheduler(
         eng, strategy=from_name(strategy, **kw), lane_timeout=lane_timeout,

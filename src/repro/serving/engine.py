@@ -403,7 +403,7 @@ class InferenceEngine:
     kv_spill: Optional[HostSpillPool] = None
 
     def __post_init__(self):
-        self.cache = self.arch.init_cache(self.n_lanes, self.max_len)
+        self.cache = self._build_cache()
         self.lengths = jnp.zeros((self.n_lanes,), jnp.int32)
         self.active = np.zeros((self.n_lanes,), bool)
         self.last_token = jnp.zeros((self.n_lanes,), jnp.int32)
@@ -495,6 +495,12 @@ class InferenceEngine:
             return logits[-1], cache, lengths
 
         self._extend = _extend
+
+    def _build_cache(self):
+        """The decode cache of every stack, one batch row per lane: KV of
+        ``max_len`` rows (the window, for windowed layers) and any
+        recurrent state.  The paged engine builds page pools instead."""
+        return self.arch.init_cache(self.n_lanes, self.max_len)
 
     def _count_dispatch(self, n: int = 1, rung: Optional[int] = None) -> None:
         """Record ``n`` jitted model-step dispatches, one of them a ladder

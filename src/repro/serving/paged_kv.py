@@ -14,19 +14,21 @@ vLLM-style:
 * :class:`PagedKVView` — the :class:`~repro.serving.kv.KVView` the
   scheduler consumes: lane allocation delegated to the dense
   :class:`~repro.serving.engine.KVPartition` (reservations keep working),
-  capacity additionally min-bounded by the page budget, and — under
-  paged compute — per-template lane reservations translated into **page
-  quotas** (a template's guaranteed share of physical pages).
-* :class:`PagedInferenceEngine` — the serving engine at page granularity.
-  For eligible architectures (:func:`~repro.models.paged_decode.
-  supports_paged_decode` — full-context dense/MoE stacks) the dense
-  per-lane backing store is **dropped**: KV lives only in shared physical
-  page arrays ``(L, n_pages + 1, page_size, Hkv, hd)`` and every decode
-  tick dispatches :func:`~repro.models.paged_decode.paged_decode_step`,
-  whose attention goes through the registry's ``paged_decode_attention``
-  kernel/ref pair (Pallas on TPU or under interpret mode, pure-jnp ref
-  elsewhere).  Outputs stay bit-identical to the dense engine at the
-  greedy-token level.  Three consequences:
+  capacity additionally min-bounded by the page budget, and per-template
+  lane reservations translated into **page quotas** (a template's
+  guaranteed share of physical pages).
+* :class:`PagedInferenceEngine` — the serving engine at page granularity,
+  for the architectures :func:`~repro.models.paged_decode.
+  supports_paged_decode` accepts (full-context dense/MoE stacks; it
+  refuses any other, which :class:`~repro.serving.engine.InferenceEngine`
+  serves).  KV lives only in shared physical page arrays
+  ``(L, n_pages + 1, page_size, Hkv, hd)`` — no dense per-lane cache is
+  ever built — and every decode tick dispatches
+  :func:`~repro.models.paged_decode.paged_decode_step`, whose attention
+  goes through the registry's ``paged_decode_attention`` kernel/ref pair
+  (Pallas on TPU or under interpret mode, pure-jnp ref elsewhere).
+  Outputs stay bit-identical to the dense engine at the greedy-token
+  level.  Three consequences:
 
   - **oversubscription** — ``n_pages`` decouples from
     ``n_lanes * max_len / page_size``: an under-provisioned pool admits
@@ -36,20 +38,15 @@ vLLM-style:
     ``on_lane_evicted`` / :meth:`~PagedInferenceEngine.drain_evictions`
     so the victim re-queues and later restores;
   - **spill/restore/commit** move pages through arbitrary physical
-    frames (no identity mapping), still page-granular: spill copies only
-    the ``ceil(length / page_size)`` valid pages, restore splices the
-    first ``prefetch_pages`` now and queues the tail, commit splices
-    only the pages each prompt actually fills;
+    frames, page-granular: spill copies only the
+    ``ceil(length / page_size)`` valid pages, restore splices the first
+    ``prefetch_pages`` now and queues the tail, commit splices only the
+    pages each prompt actually fills;
   - **fused megabatch dispatch** — :meth:`~PagedInferenceEngine.
     stage_chunk` lets the scheduler fold the next staged chunked-prefill
     chunk *into* the decode tick's device program: one dispatch covers
     the decode batch (over shared block tables) plus the chunk's scan,
     so overlap mode stops paying two dispatches per tick boundary.
-
-  Architectures paged decode cannot cover (sliding-window, SSM/hybrid
-  state) keep PR 6's dense-compute mode: identity page frames
-  (``lane * pages_per_lane + j``), page-granular *motion* only, and the
-  ordinary dense decode step — bit-identical by construction.
 
 Stale rows past a request's valid pages are never read: attention masks
 ``kpos < length + 1`` and decode writes position ``length`` before ever
@@ -145,11 +142,9 @@ class PagedKVPool:
     Pure bookkeeping: the pool tracks which physical page backs each
     logical slot of each table, not the page contents (those live in
     whatever array the caller pages — the engine's page arrays, a host
-    buffer).  ``alloc_table(key, pages=...)`` claims *specific* free
-    pages (the dense-compute engine's identity frames);
-    ``alloc_table(key, n=...)`` takes any ``n`` free pages, evicting
-    least-recently-used unpinned tables to :attr:`host_tables` (or the
-    ``on_evict`` callback) when the free list runs dry.  Pages are
+    buffer).  ``alloc_table(key, n=...)`` takes any ``n`` free pages,
+    evicting least-recently-used unpinned tables to :attr:`host_tables`
+    (or the ``on_evict`` callback) when the free list runs dry.  Pages are
     refcounted so :meth:`share` can alias a prefix across tables; a page
     returns to the free list only when its last table drops it.
     """
@@ -207,20 +202,19 @@ class PagedKVPool:
         out[: len(pages)] = pages
         return out
 
-    def alloc_table(self, key, n: Optional[int] = None,
-                    pages: Optional[list[int]] = None) -> list[int]:
-        """Create ``key``'s table from ``n`` free pages (any; LRU-evicting
-        on pressure) or the explicitly named free ``pages``."""
+    def alloc_table(self, key, n: int) -> list[int]:
+        """Create ``key``'s table from ``n`` free pages (LRU-evicting on
+        pressure)."""
         if key in self._tables:
             raise ValueError(f"table {key!r} already allocated")
-        got = self._claim(n, pages)
+        got = self._claim(n)
         self._tables[key] = got
         return list(got)
 
-    def extend_table(self, key, n: Optional[int] = None,
-                     pages: Optional[list[int]] = None) -> list[int]:
-        """Append pages to ``key``'s table (decode crossed a boundary)."""
-        new = self._claim(n, pages)
+    def extend_table(self, key, n: int) -> list[int]:
+        """Append ``n`` pages to ``key``'s table (decode crossed a
+        boundary)."""
+        new = self._claim(n)
         self._tables[key].extend(new)
         self._tables.move_to_end(key)
         return new
@@ -336,16 +330,7 @@ class PagedKVPool:
                 "evicted": self.evicted, "host_tables": len(self.host_tables)}
 
     # ------------------------------------------------------------- internals
-    def _claim(self, n: Optional[int], pages: Optional[list[int]]) -> list[int]:
-        if (n is None) == (pages is None):
-            raise ValueError("pass exactly one of n= / pages=")
-        if pages is not None:
-            for p in pages:
-                if self._ref[p] != 0:
-                    raise ValueError(f"page {p} is not free")
-                self._free.remove(p)
-                self._ref[p] = 1
-            return list(pages)
+    def _claim(self, n: int) -> list[int]:
         while len(self._free) < n:
             self._evict_one()
         got = [self._free.pop(0) for _ in range(n)]
@@ -476,15 +461,18 @@ class PagedKVView:
 class PagedInferenceEngine(InferenceEngine):
     """Serving engine with paged KV compute + motion (module docstring).
 
-    ``page_size`` must divide ``max_len``; ``prefetch_pages`` is how many
-    pages a restore splices synchronously before resuming decode (the
-    tail streams in before the next tick).  ``n_pages`` sizes the
-    physical pool — default ``n_lanes * max_len / page_size`` (full
-    provisioning); smaller values oversubscribe (paged-compute archs
-    only) and lean on mid-decode eviction.  ``use_kernel``/``interpret``
-    feed the registry dispatch policy for the paged attention op;
-    ``interpret=None`` reads ``REPRO_KERNEL_INTERPRET`` (the CI kernels
-    job's switch).  ``prefix_share`` (paged-compute only) turns on
+    Raises ``ValueError`` for an architecture paged decode cannot run
+    (:func:`~repro.models.paged_decode.supports_paged_decode`), before
+    allocating anything: :class:`~repro.serving.engine.InferenceEngine`
+    serves those.  ``page_size`` must divide ``max_len``;
+    ``prefetch_pages`` is how many pages a restore splices synchronously
+    before resuming decode (the tail streams in before the next tick).
+    ``n_pages`` sizes the physical pool — default
+    ``n_lanes * max_len / page_size`` (full provisioning); smaller values
+    oversubscribe and lean on mid-decode eviction.
+    ``use_kernel``/``interpret`` feed the registry dispatch policy for the
+    paged attention op; ``interpret=None`` reads ``REPRO_KERNEL_INTERPRET``
+    (the CI kernels job's switch).  ``prefix_share`` turns on
     prefix-granular cross-request KV sharing: synchronous admission
     consults a :class:`~repro.core.lane_policy.PrefixIndex`, aliases the
     page-aligned prompt prefix a resident lane already computed
@@ -500,27 +488,26 @@ class PagedInferenceEngine(InferenceEngine):
     prefix_share: bool = False
 
     def __post_init__(self):
-        super().__post_init__()
+        if not supports_paged_decode(self.arch.cfg):
+            raise ValueError(
+                f"paged decode cannot run {self.arch.cfg.name!r} (sliding-"
+                "window, recurrent or encoder-decoder layers): serve it "
+                "with InferenceEngine")
         if self.max_len % self.page_size:
             raise ValueError("page_size must divide max_len")
         if self.prefetch_pages < 1:
             raise ValueError("prefetch_pages must be >= 1")
         self.pages_per_lane = self.max_len // self.page_size
-        self.paged_compute = supports_paged_decode(self.arch.cfg)
-        full = self.n_lanes * self.pages_per_lane
         if self.n_pages is None:
-            self.n_pages = full
-        if self.n_pages != full and not self.paged_compute:
-            raise ValueError(
-                "n_pages decoupled from n_lanes * max_len / page_size needs "
-                "a paged-decode-capable arch (dense/MoE, full context)")
+            self.n_pages = self.n_lanes * self.pages_per_lane
         if self.n_pages < self.pages_per_lane:
             raise ValueError(
                 "n_pages must cover at least one lane "
                 f"({self.pages_per_lane} pages)")
+        super().__post_init__()
         self.pool = PagedKVPool(self.n_pages, self.page_size)
         quota = None
-        if self.paged_compute and self.partition.shares:
+        if self.partition.shares:
             quota = {t: k * self.n_pages // self.n_lanes
                      for t, k in self.partition.shares.items()}
         self._kv_view = PagedKVView(self.partition, self.pool,
@@ -550,10 +537,6 @@ class PagedInferenceEngine(InferenceEngine):
         # Prefix sharing: index + counters.  The analytic per-token FLOPs
         # (2 * params, the standard dense-forward estimate) turns pages
         # aliased instead of prefilled into prefill_flops_saved.
-        if self.prefix_share and not self.paged_compute:
-            raise ValueError(
-                "prefix_share needs a paged-decode-capable arch "
-                "(dense/MoE, full context)")
         self.prefix_index: Optional[PrefixIndex] = (
             PrefixIndex(self.page_size) if self.prefix_share else None)
         self.prefix_hits = 0
@@ -562,24 +545,12 @@ class PagedInferenceEngine(InferenceEngine):
         self._flops_per_token = 2 * sum(
             int(np.prod(a.shape))
             for a in jax.tree_util.tree_leaves(self.params))
-        if not self.paged_compute:
-            return
         # Partial eviction leaves refcounted prefix pages resident while
         # their spill entry lives on host; if the spill pool silently
         # drops the entry, those holds must be released or the pages leak.
         spill = self.partition.spill
         if spill is not None and getattr(spill, "on_drop", None) is None:
             spill.on_drop = self._release_entry_holds
-        # Drop the dense per-lane backing store: KV lives in shared page
-        # arrays (L, n_pages + 1, page_size, Hkv, hd).  Slot n_pages is
-        # the trash page inactive lanes scatter into; block tables never
-        # reference it and the pool never allocates it.
-        P, ps = self.n_pages + 1, self.page_size
-
-        def pageify(a):
-            return jnp.zeros((a.shape[0], P, ps) + a.shape[3:], a.dtype)
-
-        self.cache = jax.tree_util.tree_map(pageify, self.cache)
         self._interpret = (self.interpret if self.interpret is not None
                            else registry.interpret_default())
         self._paged_decode, self._fused = paged_step_programs(
@@ -615,24 +586,24 @@ class PagedInferenceEngine(InferenceEngine):
 
         self._shared_tail_fn = _shared_tail
 
+    def _build_cache(self):
+        """Zeroed page pools ``(L, n_pages + 1, page_size, Hkv, hd)``, one
+        per leaf of the dense cache, shaped from it without allocating it.
+        Slot ``n_pages`` is the trash page inactive lanes scatter into;
+        block tables never reference it and the pool never allocates it."""
+        shape = (self.n_pages + 1, self.page_size)
+        dense = jax.eval_shape(
+            lambda: self.arch.init_cache(self.n_lanes, self.max_len))
+        return jax.tree_util.tree_map(
+            lambda a: jnp.zeros(a.shape[:1] + shape + a.shape[3:], a.dtype),
+            dense)
+
     @property
     def kv(self) -> PagedKVView:
         """The page-budget-bounded :class:`~repro.serving.kv.KVView`."""
         return self._kv_view
 
-    # ---------------------------------------------------------- page frames
-    def _frames(self, lane: int, start: int, stop: int) -> list[int]:
-        """Identity physical frames for ``lane``'s logical pages
-        [start, stop) — dense-compute mode only, where page ``j`` of lane
-        ``L`` lives in device frame ``L * pages_per_lane + j``."""
-        base = lane * self.pages_per_lane
-        return [base + j for j in range(start, stop)]
-
-    def _seq_leaf(self, dst) -> bool:
-        # KV leaves carry the sequence axis at position 2 ((L, B, S, H, d));
-        # SSM/conv state leaves do not and always move whole.
-        return dst.ndim >= 3 and dst.shape[2] == self.max_len
-
+    # ---------------------------------------------------------- page tables
     def _pages_used_by(self, template: Optional[str]) -> int:
         """Physical pages currently held by ``template``'s lanes (the
         page-quota accounting hook the :class:`PagedKVView` consults)."""
@@ -642,26 +613,19 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _open_table(self, lane: int, length: int, avoid=frozenset()) -> None:
         """(Re)create ``lane``'s pinned block table covering ``length``
-        written rows plus the next write position.  Paged-compute mode
-        takes any free frames (evicting other lanes under pressure, never
-        one in ``avoid``); dense-compute mode uses identity frames."""
+        written rows plus the next write position, from any free pages
+        (evicting other lanes under pressure, never one in ``avoid``)."""
         n = min(self.pages_per_lane, length // self.page_size + 1)
-        if self.paged_compute:
-            self._make_room(n, avoid=set(avoid) | {lane})
-            self.pool.alloc_table(lane, n=n)
-        else:
-            self.pool.alloc_table(lane, pages=self._frames(lane, 0, n))
+        self._make_room(n, avoid=set(avoid) | {lane})
+        self.pool.alloc_table(lane, n=n)
         self.pool.pin(lane)
 
     def _ensure_pages(self, lane: int, n: int) -> None:
         n = min(n, self.pages_per_lane)
         have = len(self.pool.table(lane))
         if n > have:
-            if self.paged_compute:
-                self._make_room(n - have, avoid={lane})
-                self.pool.extend_table(lane, n=n - have)
-            else:
-                self.pool.extend_table(lane, pages=self._frames(lane, have, n))
+            self._make_room(n - have, avoid={lane})
+            self.pool.extend_table(lane, n=n - have)
 
     # --------------------------------------------------- page-pressure evict
     def _make_room(self, need: int, avoid=frozenset()) -> None:
@@ -843,47 +807,11 @@ class PagedInferenceEngine(InferenceEngine):
         if pages:
             self.pool.decref_pages(pages)
 
-    def commit_prefill(self, staged: StagedPrefill,
-                       n: Optional[int] = None) -> tuple[int, int]:
-        """Commit + a pinned block table per lane (identity frames in
-        dense-compute mode; paged-compute opens tables inside the splice,
-        which needs them before any page write)."""
-        shape = super().commit_prefill(staged, n)
-        if self.paged_compute or staged.parts:
-            return shape  # parts recursed through here and built tables
-        k = len(staged.requests) if n is None else min(n, len(staged.requests))
-        for r, plen in zip(staged.requests[:k], staged.plens[:k]):
-            self._open_table(r.lane, int(plen))
-        return shape
-
     def _insert_staged(self, staged: StagedPrefill, lanes: list[int]) -> None:
-        """Page-granular commit splice.
-
-        Paged-compute: per-request tables are opened (never evicting a
-        batch-mate) and the pages each prompt fills are scattered into
-        physical frames (:meth:`_scatter_prompt`).  Dense-compute keeps
-        the bucket-max row splice into the per-lane cache.
-        """
+        """Page-granular commit splice: per-request tables are opened
+        (never evicting a batch-mate) and the pages each prompt fills are
+        scattered into physical frames (:meth:`_scatter_prompt`)."""
         ps = self.page_size
-        if not self.paged_compute:
-            plen = int(np.max(staged.plens[: len(lanes)]))
-            n_rows = min(self.max_len, max(1, self.pool.pages_for(plen)) * ps)
-            idx = jnp.asarray(lanes)
-
-            def one(dst, src):
-                take = src[:, : len(lanes)]
-                if self._seq_leaf(dst):
-                    return dst.at[:, idx, :n_rows].set(
-                        take[:, :, :n_rows].astype(dst.dtype))
-                return dst.at[:, idx].set(take.astype(dst.dtype))
-
-            self.cache = jax.tree_util.tree_map(one, self.cache, staged.cache)
-            for a in jax.tree_util.tree_leaves(staged.cache):
-                rows = n_rows if self._seq_leaf(a) else a.shape[2] if a.ndim >= 3 else 1
-                per_row = int(np.prod(a.shape[3:])) if a.ndim >= 3 else int(np.prod(a.shape[2:]))
-                self.kv_bytes_moved += (a.dtype.itemsize * a.shape[0]
-                                        * len(lanes) * rows * per_row)
-            return
         avoid = set(lanes)
         for i, lane in enumerate(lanes):
             r = staged.requests[i]
@@ -927,11 +855,9 @@ class PagedInferenceEngine(InferenceEngine):
 
     def _warm_inserts(self, src) -> None:
         """Compile the page insert of every rung: ``src`` scattered whole
-        into the trash page.  Dense-compute mode splices rows per page
-        count and compiles as prompts come."""
-        if self.paged_compute:
-            for rung in self.prefill_rungs():
-                self._scatter_prompt(src, 0, [], rung)
+        into the trash page."""
+        for rung in self.prefill_rungs():
+            self._scatter_prompt(src, 0, [], rung)
 
     # ------------------------------------------------------- fused dispatch
     def stage_chunk(self, staged: StagedPrefill) -> bool:
@@ -939,11 +865,11 @@ class PagedInferenceEngine(InferenceEngine):
         dispatch (fused megabatch): the chunk's decode-path scan and the
         paged decode batch compile into ONE device program, so overlap
         mode pays one dispatch per tick boundary instead of two.  Returns
-        ``False`` when fusion does not apply (dense-compute mode, a chunk
-        already staged, nothing pending, or no active decode batch to
-        fuse with) — the caller then advances the chunk on its own.
+        ``False`` when fusion does not apply (a chunk already staged,
+        nothing pending, or no active decode batch to fuse with) — the
+        caller then advances the chunk on its own.
         """
-        if not self.paged_compute or self._fused_chunk is not None:
+        if self._fused_chunk is not None:
             return False
         part = staged
         if staged.parts:
@@ -961,18 +887,8 @@ class PagedInferenceEngine(InferenceEngine):
         restore tails, grow block tables (evicting under page pressure),
         fence copy-on-write pages, and upload the tables, the lane mask and
         the sampling parameters.  The step is fused with any staged
-        prefill chunk.  Dense-compute mode grows the tables of the
-        ordinary dense decode step instead."""
+        prefill chunk."""
         self._flush_restores()
-        if not self.paged_compute:
-            if self.active.any():
-                ln = self._host(self.lengths)
-                for lane in np.nonzero(self.active)[0]:
-                    # decode writes position `length` this tick: its page
-                    # must be in the table before the write.
-                    self._ensure_pages(int(lane),
-                                       int(ln[lane]) // self.page_size + 1)
-            return super()._prepare_decode()
         part, self._fused_chunk = self._fused_chunk, None
         if not self.active.any():
             if part is not None:  # nothing to fuse with: plain resume
@@ -993,13 +909,10 @@ class PagedInferenceEngine(InferenceEngine):
         return (part, self._device_tables(), jnp.asarray(self.active),
                 jnp.asarray(self.lane_temps), jnp.asarray(self.lane_seeds))
 
-    def _dispatch_decode(self, *args):
+    def _dispatch_decode(self, part, tables, active_dev, temps, seeds):
         """Dispatch the paged decode step prepared by
         :meth:`_prepare_decode` (fused with ``part``'s next chunk when one
         is staged); returns the new tokens, still on the device."""
-        if not self.paged_compute:
-            return super()._dispatch_decode()
-        part, tables, active_dev, temps, seeds = args
         old_pools = jax.tree_util.tree_leaves(self.cache)
         if part is None:
             nxt, self.cache = self._paged_decode(
@@ -1068,8 +981,8 @@ class PagedInferenceEngine(InferenceEngine):
     def spill(self, lane: int, key, template: Optional[str] = None) -> bool:
         """Stage only the lane's VALID pages to host (vs the dense
         engine's full ``max_len`` rows) — the page-granularity bytes win.
-        Paged-compute gathers the pages from their physical frames; the
-        host entry layout (contiguous rows) is shared with dense mode.
+        The pages are gathered from their physical frames into contiguous
+        host rows.
 
         **Partial eviction**: leading pages still aliased by another live
         table (a shared prefix) are NOT copied — they stay resident, kept
@@ -1087,24 +1000,17 @@ class PagedInferenceEngine(InferenceEngine):
         ps = self.page_size
         npg = max(1, self.pool.pages_for(length))
         n_rows = min(self.max_len, npg * ps)
-        prefix_pages: list[int] = []
-        tail_start = 0
-        if self.paged_compute:
-            pages = self.pool.pages(lane)[:npg]
-            keep = min(self.pool.shared_prefix_pages(lane), npg)
-            tail_start = keep * ps
-            prefix_pages = list(pages[:keep])
-            idx = jnp.asarray(np.asarray(pages[keep:npg], np.int32))
-            rows = jax.tree_util.tree_map(
-                lambda a: self._host(
-                    a[:, idx].reshape(a.shape[0], (npg - keep) * ps,
-                                      *a.shape[3:])
-                    [:, : n_rows - tail_start]),
-                self.cache)
-        else:
-            rows = jax.tree_util.tree_map(
-                lambda a: self._host(a[:, lane, :n_rows])
-                if self._seq_leaf(a) else self._host(a[:, lane]), self.cache)
+        pages = self.pool.pages(lane)[:npg]
+        keep = min(self.pool.shared_prefix_pages(lane), npg)
+        tail_start = keep * ps
+        prefix_pages = list(pages[:keep])
+        idx = jnp.asarray(np.asarray(pages[keep:npg], np.int32))
+        rows = jax.tree_util.tree_map(
+            lambda a: self._host(
+                a[:, idx].reshape(a.shape[0], (npg - keep) * ps,
+                                  *a.shape[3:])
+                [:, : n_rows - tail_start]),
+            self.cache)
         entry = {
             "rows": rows,
             "n_rows": n_rows,
@@ -1129,9 +1035,9 @@ class PagedInferenceEngine(InferenceEngine):
     def try_restore(self, key, template: Optional[str] = None) -> Optional[int]:
         """Restore spilled pages: first ``prefetch_pages`` now, tail
         queued for the next tick — decode resumes after the prefetch
-        instead of waiting for the whole lane.  Paged-compute additionally
-        requires the pages to be free RIGHT NOW (a restore never evicts —
-        that would thrash against the eviction that spilled it)."""
+        instead of waiting for the whole lane.  The pages must be free
+        RIGHT NOW (a restore never evicts — that would thrash against the
+        eviction that spilled it)."""
         pool = self.partition.spill
         if pool is None or key not in pool or self.n_free_for(template) <= 0:
             return None
@@ -1142,50 +1048,30 @@ class PagedInferenceEngine(InferenceEngine):
         n_rows = entry["n_rows"]
         tail_start = entry.get("tail_start", 0)
         prefix_pages = entry.get("prefix_pages") or []
-        head = min(n_rows, self.prefetch_pages * self.page_size)
-        if self.paged_compute:
-            k = len(prefix_pages)
-            total = min(self.pages_per_lane,
-                        entry["length"] // self.page_size + 1)
-            need = max(0, total - k)
-            if self.pool.n_free_pages < need:
-                pool.put(key, template, entry)  # not enough pages yet
-                return None
-            lane = self.partition.alloc(template)
-            if k:
-                # Re-adopt the still-resident shared prefix: the entry's
-                # refcount hold TRANSFERS into the new table (no copy, no
-                # incref), and only the private tail needs page claims +
-                # a host→device splice.
-                self.pool.adopt_table(lane, prefix_pages)
-                if need > 0:
-                    self.pool.extend_table(lane, n=need)
-                self.pool.pin(lane)
-            else:
-                self._open_table(lane, entry["length"])
-            self._lane_meta[lane] = (key, template)
-            self.lane_temps[lane] = entry.get("temp", 0.0)
-            self.lane_seeds[lane] = entry.get("seed", 0)
-            head = min(n_rows, tail_start + self.prefetch_pages
-                       * self.page_size)
-            self._write_rows(lane, rows, tail_start, head, base=tail_start)
+        k = len(prefix_pages)
+        total = min(self.pages_per_lane,
+                    entry["length"] // self.page_size + 1)
+        need = max(0, total - k)
+        if self.pool.n_free_pages < need:
+            pool.put(key, template, entry)  # not enough pages yet
+            return None
+        lane = self.partition.alloc(template)
+        if k:
+            # Re-adopt the still-resident shared prefix: the entry's
+            # refcount hold TRANSFERS into the new table (no copy, no
+            # incref), and only the private tail needs page claims +
+            # a host→device splice.
+            self.pool.adopt_table(lane, prefix_pages)
+            if need > 0:
+                self.pool.extend_table(lane, n=need)
+            self.pool.pin(lane)
         else:
-            lane = self.partition.alloc(template)
-
-            def one(dst, src):
-                src = jnp.asarray(src)
-                if self._seq_leaf(dst):
-                    return dst.at[:, lane, :head].set(
-                        src[:, :head].astype(dst.dtype))
-                return dst.at[:, lane].set(src.astype(dst.dtype))
-
-            self.cache = jax.tree_util.tree_map(one, self.cache, rows)
-            moved = sum(
-                (a.dtype.itemsize * a.shape[0] * head * int(np.prod(a.shape[2:])))
-                if a.ndim >= 3 and a.shape[1] == n_rows else a.nbytes
-                for a in map(np.asarray, jax.tree_util.tree_leaves(rows)))
-            self.kv_bytes_moved += moved
             self._open_table(lane, entry["length"])
+        self._lane_meta[lane] = (key, template)
+        self.lane_temps[lane] = entry.get("temp", 0.0)
+        self.lane_seeds[lane] = entry.get("seed", 0)
+        head = min(n_rows, tail_start + self.prefetch_pages * self.page_size)
+        self._write_rows(lane, rows, tail_start, head, base=tail_start)
         if head < n_rows:
             self._pending_restore[lane] = (rows, head, n_rows, tail_start)
         ln = self._host(self.lengths, copy=True)
@@ -1201,7 +1087,7 @@ class PagedInferenceEngine(InferenceEngine):
                     base: int = 0) -> None:
         """Scatter host rows covering logical positions [start, stop)
         (page-aligned bounds) into ``lane``'s physical frames, with byte
-        accounting (paged-compute).  ``base`` is the logical position of
+        accounting.  ``base`` is the logical position of
         ``rows``' first row — a partial eviction's host copy starts at
         ``tail_start``, not 0."""
         if stop <= start:
@@ -1232,48 +1118,24 @@ class PagedInferenceEngine(InferenceEngine):
             items = list(self._pending_restore.items())
             self._pending_restore.clear()
         for ln_, (rows, start, stop, base) in items:
-            if self.paged_compute:
-                self._write_rows(ln_, rows, start, stop, base=base)
-                continue
-
-            def one(dst, src, ln_=ln_, start=start, stop=stop):
-                if self._seq_leaf(dst):
-                    return dst.at[:, ln_, start:stop].set(
-                        jnp.asarray(src)[:, start:stop].astype(dst.dtype))
-                return dst
-
-            self.cache = jax.tree_util.tree_map(one, self.cache, rows)
-            for a in map(np.asarray, jax.tree_util.tree_leaves(rows)):
-                if a.ndim >= 3 and a.shape[1] == stop:
-                    self.kv_bytes_moved += (a.dtype.itemsize * a.shape[0]
-                                            * (stop - start)
-                                            * int(np.prod(a.shape[2:])))
+            self._write_rows(ln_, rows, start, stop, base=base)
 
     # ------------------------------------------------------------ paged view
     def paged_view(self, stack: str = "layers") -> Optional[dict]:
         """The active lanes' KV as the paged-kernel layout.
 
         Returns ``{"k_pages", "v_pages", "block_tables", "lengths",
-        "lanes"}`` for one transformer ``stack`` (layer 0).  Paged-compute
-        mode returns the live page arrays directly (decode_tick consumes
-        exactly this layout); dense-compute mode cuts pages from the
-        per-lane cache at identity frames.  ``None`` when the stack has
-        no k/v leaves or nothing is active.
+        "lanes"}`` for one transformer ``stack`` (layer 0): the live page
+        arrays, the layout decode_tick consumes.  ``None`` when the stack
+        has no k/v leaves or nothing is active.
         """
         entry = self.cache.get(stack) if hasattr(self.cache, "get") else None
         if not entry or "k" not in entry or not self.active.any():
             return None
         lanes = [int(x) for x in np.nonzero(self.active)[0]]
-        ps, ppl = self.page_size, self.pages_per_lane
-        if self.paged_compute:
-            k_pages, v_pages = entry["k"][0], entry["v"][0]
-        else:
-            k0, v0 = entry["k"][0], entry["v"][0]  # (B, S, Hkv, hd) layer 0
-            hkv, hd = k0.shape[2], k0.shape[3]
-            k_pages = jnp.reshape(k0, (self.n_lanes * ppl, ps, hkv, hd))
-            v_pages = jnp.reshape(v0, (self.n_lanes * ppl, ps, hkv, hd))
-        tables = np.stack([self.pool.block_table(lane, ppl) for lane in lanes])
+        tables = np.stack([self.pool.block_table(lane, self.pages_per_lane)
+                           for lane in lanes])
         lengths = self._host(self.lengths)[lanes].astype(np.int32)
-        return {"k_pages": k_pages, "v_pages": v_pages,
+        return {"k_pages": entry["k"][0], "v_pages": entry["v"][0],
                 "block_tables": jnp.asarray(tables),
                 "lengths": jnp.asarray(lengths), "lanes": lanes}

@@ -44,7 +44,7 @@ def test_oversubscribed_admission_bound(setup):
     arch, params = setup
     eng = PagedInferenceEngine(arch, params, n_lanes=4, max_prompt_len=16,
                                max_len=32, page_size=8, n_pages=8)
-    assert eng.paged_compute and eng.pages_per_lane == 4
+    assert eng.pages_per_lane == 4
     # 4 free lanes, but only 8 pages = 2 whole-lane budgets.
     assert eng.partition.n_free == 4
     assert eng.kv.n_free == 2 and eng.kv.n_free_for(None) == 2
@@ -243,8 +243,44 @@ def test_serve_requests_drives_paged_main_path(setup):
     eng, sched, done = serve_requests(arch, params, reqs, lanes=2,
                                       max_len=32, max_prompt_len=16,
                                       page_size=8)
-    assert isinstance(eng, PagedInferenceEngine) and eng.paged_compute
+    assert isinstance(eng, PagedInferenceEngine)
     assert sched.resilience is None
     assert sorted(r.rid for r in done) == [0, 1, 2]
     assert all(len(r.generated) == 4 for r in done)
     assert eng.decode_steps > 0 and eng.dispatches > 0
+
+
+def test_serve_requests_serves_a_windowed_arch_on_the_dense_engine(setup):
+    """``serve_requests`` serves an arch paged decode cannot run (a
+    sliding window) on the dense engine, with the greedy tokens of the
+    same prompts on an ``InferenceEngine`` built directly."""
+    from repro.launch.serve import serve_requests
+
+    arch, _ = setup
+    win = dataclasses.replace(arch, cfg=dataclasses.replace(arch.cfg,
+                                                            attn_window=8))
+    params = win.init(jax.random.PRNGKey(0))
+    rng = np.random.default_rng(5)
+    # one prompt length, a power of two: every batch pads to the same
+    # bucket, so the window sees the same rows however requests batch
+    prompts = [rng.integers(1, 200, size=8).astype(np.int32)
+               for _ in range(3)]
+
+    def reqs():
+        return [Request(rid=i, prompt=p, max_new_tokens=12)
+                for i, p in enumerate(prompts)]
+
+    eng, _, done = serve_requests(win, params, reqs(), lanes=4, max_len=32,
+                                  max_prompt_len=16, page_size=8)
+    assert type(eng) is InferenceEngine
+    served = {r.rid: r.generated for r in done}
+
+    direct = InferenceEngine(win, params, n_lanes=4, max_prompt_len=16,
+                             max_len=32)
+    want = reqs()
+    direct.admit(want, None)
+    for _ in range(11):
+        out = direct.decode_tick()
+        for r in want:
+            r.generated.append(out[r.lane])
+    assert served == {r.rid: r.generated for r in want}

@@ -254,14 +254,25 @@ def test_prefix_hit_bit_identical_and_zero_cost(setup):
     assert ratio > 1.5
 
 
-def test_prefix_share_requires_paged_compute(setup):
+@pytest.mark.parametrize("prefix_share", [False, True])
+def test_paged_engine_refuses_an_arch_paged_decode_cannot_run(
+        setup, monkeypatch, prefix_share):
+    """A windowed arch is refused, with or without prefix sharing, before
+    the engine builds any cache: InferenceEngine serves it."""
     arch, params = setup
     win = dataclasses.replace(arch, cfg=dataclasses.replace(arch.cfg,
                                                             attn_window=8))
     p = win.init(jax.random.PRNGKey(0))
-    with pytest.raises(ValueError, match="prefix_share"):
+
+    def no_cache(self):
+        raise AssertionError("a cache was built before the refusal")
+
+    monkeypatch.setattr(InferenceEngine, "_build_cache", no_cache)
+    monkeypatch.setattr(PagedInferenceEngine, "_build_cache", no_cache)
+    with pytest.raises(ValueError, match="InferenceEngine"):
         PagedInferenceEngine(win, p, n_lanes=2, max_prompt_len=16,
-                             max_len=32, page_size=8, prefix_share=True)
+                             max_len=32, page_size=8,
+                             prefix_share=prefix_share)
 
 
 def test_cow_guard_forks_before_shared_page_write(setup):
